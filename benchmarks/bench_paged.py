@@ -4,12 +4,12 @@ Three measurements on the same reduced model:
 
 1. **Serving throughput** — the identical heavy-tail trace through a
    paged and a striped (pooled) ``ContinuousBatchingEngine``; tokens/s
-   for each.  The whole measurement runs in a CHILD process whose CPU
-   affinity is set to one core BEFORE the interpreter starts (XLA then
-   sizes its thread pool to a single worker) — single-core time
-   measures the engines' WORK, where unpinned per-op multithreading
-   just lets whichever engine has the biggest single ops soak up the
-   machine's idle-core weather, and pinning after XLA has already
+   for each.  On the CPU backend the whole measurement runs in a CHILD
+   process whose CPU affinity is set to one core BEFORE the interpreter
+   starts (XLA then sizes its thread pool to a single worker) —
+   single-core time measures the engines' WORK, where unpinned per-op
+   multithreading just lets whichever engine has the biggest single ops
+   soak up the machine's idle-core weather, and pinning after XLA has already
    spawned its pool leaves two workers contending on one core.  An
    untimed warmup drive absorbs compilation and first-touch
    allocation; each timed repeat runs the two engines back-to-back
@@ -113,7 +113,7 @@ def _drive(eng, trace, sample=None):
     return n_steps
 
 
-def _spawn_pinned_throughput(report) -> bool:
+def _spawn_pinned_throughput(report) -> None:
     """Run the throughput section in a child process pinned to ONE cpu
     from exec.  Per-op multithreading adds no serving capacity on a
     loaded host — under real traffic every core is already serving
@@ -122,11 +122,9 @@ def _spawn_pinned_throughput(report) -> bool:
     machine's spare-core weather instead of the engines' work.  The
     affinity must be set before the interpreter starts: XLA sizes its
     thread pool at startup, and pinning an already-spawned pool leaves
-    its workers contending on the single core.  Returns False when the
-    child cannot run (non-Linux, no module path); the caller then
-    falls back to an in-process, unpinned measurement."""
-    if not hasattr(os, "sched_setaffinity"):
-        return False
+    its workers contending on the single core.  Only the CPU backend
+    runs this way (an accelerator belongs to the parent process, which
+    has touched JAX); any child failure raises."""
     cpu = min(os.sched_getaffinity(0))
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
@@ -141,19 +139,17 @@ def _spawn_pinned_throughput(report) -> bool:
             timeout=1800, check=True,
             preexec_fn=lambda: os.sched_setaffinity(0, {cpu}))
     except subprocess.CalledProcessError as e:
-        # a real failure inside the section (e.g. the bit-equality
-        # assert) must surface, not silently degrade to the fallback
         raise RuntimeError(
             f"pinned throughput child failed:\n{e.stderr}") from e
-    except (subprocess.SubprocessError, OSError):
-        return False
     parsed = False
     for line in proc.stdout.splitlines():
         if line.startswith("METRIC,"):
             _, name, value, derived = line.split(",", 3)
             report(name, float(value), derived)
             parsed = True
-    return parsed
+    if not parsed:
+        raise RuntimeError("pinned throughput child reported no metric:\n"
+                           f"{proc.stdout}\n{proc.stderr}")
 
 
 def _timed_drive(eng, trace, sample=None) -> float:
@@ -195,8 +191,8 @@ def _mid_generation(cfg, params, trace, *, paged: bool):
 
 def _throughput_section(report) -> None:
     """Sections 1+2 (throughput + residency), measured in THIS
-    process.  ``run`` executes it in a single-cpu child via
-    ``_spawn_pinned_throughput`` whenever the platform allows."""
+    process.  On the CPU backend ``run`` executes it in a single-cpu
+    child via ``_spawn_pinned_throughput``."""
     # wide enough that a tick is many ms of real compute — per-tick
     # times then measure the engines, not the host scheduler's quantum
     cfg = reduced(get_config("qwen2.5-3b"), d_model=256)
@@ -261,8 +257,10 @@ def _throughput_section(report) -> None:
 
 
 def run(report) -> None:
-    # ---- 1+2: throughput and residency (single-cpu child) --------------
-    if not _spawn_pinned_throughput(report):
+    # ---- 1+2: throughput and residency (single-cpu child on the CPU) ---
+    if jax.default_backend() == "cpu" and hasattr(os, "sched_setaffinity"):
+        _spawn_pinned_throughput(report)
+    else:
         _throughput_section(report)
 
     # the handoff sections compare wire bytes and pricing decisions, not

@@ -60,6 +60,7 @@ from benchmarks import (bench_autoscale, bench_cache, bench_coldstart,
                         bench_overload, bench_paged, bench_prefix,
                         bench_roofline, bench_slo, bench_trace,
                         bench_throughput)
+from repro.launch.jax_cache import enable_compile_cache
 
 MODULES = {
     "cache": bench_cache, "multicast": bench_multicast,
@@ -82,6 +83,7 @@ def main() -> None:
                     help="directory for the BENCH_<name>.json summaries")
     args = ap.parse_args()
     names = args.only.split(",") if args.only else list(MODULES)
+    enable_compile_cache()
 
     print("name,value,derived")
     rows = []

@@ -1,4 +1,4 @@
-"""Qwen2.5-3B — dense, GQA(kv=2), QKV bias, tied embeddings [hf:Qwen/Qwen2.5-0.5B]."""
+"""Qwen2.5-3B — dense, GQA(kv=2), QKV bias, tied embeddings [hf:Qwen/Qwen2.5-3B]."""
 from repro.configs.base import ModelConfig
 
 
@@ -10,5 +10,5 @@ def config() -> ModelConfig:
         layer_pattern=("attn:dense",),
         norm="rms", act="silu", qkv_bias=True, tie_embeddings=True,
         rope_theta=1_000_000.0,
-        source="hf:Qwen/Qwen2.5-0.5B",
+        source="hf:Qwen/Qwen2.5-3B",
     )

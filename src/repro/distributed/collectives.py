@@ -19,8 +19,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.multicast import Schedule
 
-from repro.compat import shard_map
-
 
 def _step_tables(schedule: Schedule):
     """Per step: (send_blk[node], recv_blk[node], perm pairs)."""
@@ -64,7 +62,7 @@ def multicast(blocks: jnp.ndarray, schedule: Schedule, mesh,
             buf = buf.at[safe].set(new)
         return buf[None]
 
-    fn = shard_map(spmd, mesh=mesh, in_specs=P(axis), out_specs=P(axis))
+    fn = jax.shard_map(spmd, mesh=mesh, in_specs=P(axis), out_specs=P(axis))
     return fn(blocks)
 
 

@@ -24,8 +24,6 @@ from repro.configs.base import ModelConfig
 from repro.models import model as mm
 from repro.serving.scheduler import DEFAULT_SLOTS, Scheduler, SeqState
 
-from repro.compat import shard_map
-
 
 def stage_params_from_trunk(cfg: ModelConfig, params, n_stages: int):
     """Reshape the scan-stacked trunk into (n_stages, layers_per_stage, ...).
@@ -85,8 +83,8 @@ def pipelined_forward(cfg: ModelConfig, params, batch: Dict, mesh,
         # only the last stage wrote non-zeros; make the result replicated
         return jax.lax.psum(outs, axis)
 
-    fn = shard_map(spmd, mesh=mesh,
-                   in_specs=(P(axis), P()), out_specs=P())
+    fn = jax.shard_map(spmd, mesh=mesh,
+                       in_specs=(P(axis), P()), out_specs=P())
     hidden = fn(stage_trunk, embeds).reshape(B, S, cfg.d_model)
     return mm._unembed(cfg, params, hidden)
 

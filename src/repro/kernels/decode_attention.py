@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import CompilerParams
+from repro.kernels.backend import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -61,7 +61,7 @@ def _kernel(q_ref, k_ref, v_ref, spos_ref, pos_ref, o_ref,
 
 @functools.partial(jax.jit, static_argnames=("window", "bk", "interpret"))
 def decode_attention(q, k, v, spos, pos, *, window=None, bk: int = 128,
-                     interpret: bool = True):
+                     interpret: bool | None = None):
     """q: (B,H,dh), k/v: (B,W,KVH,dh), spos: (B,W), pos: (B,) -> (B,H,dh)."""
     B, H, dh = q.shape
     W, KVH = k.shape[1], k.shape[2]
@@ -96,8 +96,8 @@ def decode_attention(q, k, v, spos, pos, *, window=None, bk: int = 128,
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, dh), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q.reshape(B * H, dh), kT, vT, spos, pos.reshape(B, 1))
     return out.reshape(B, H, dh)

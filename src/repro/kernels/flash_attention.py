@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import CompilerParams
+from repro.kernels.backend import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -82,7 +82,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     "causal", "window", "bq", "bk", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window=None,
                     bq: int = 128, bk: int = 128,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """q: (B,H,S,dh), k/v: (B,KVH,S,dh) -> (B,H,S,dh)."""
     B, H, S, dh = q.shape
     KVH = k.shape[1]
@@ -116,8 +116,8 @@ def flash_attention(q, k, v, *, causal: bool = True, window=None,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, dh), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q.reshape(B * H, S, dh), k.reshape(B * KVH, S, dh),
       v.reshape(B * KVH, S, dh)).reshape(B, H, S, dh)

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import CompilerParams
+from repro.kernels.backend import resolve_interpret
 
 NEG = -1e30
 
@@ -89,7 +89,7 @@ def _kernel(q_ref, k_ref, v_ref, i_ref, f_ref, o_ref,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def mlstm_chunkwise(q, k, v, i_pre, f_pre, *, chunk: int = 128,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """q,k,v: (B,H,S,dh); i_pre,f_pre: (B,H,S) raw gate pre-activations.
     k must already be scaled by 1/sqrt(dh).  Returns h (B,H,S,dh)."""
     B, H, S, dh = q.shape
@@ -115,9 +115,9 @@ def mlstm_chunkwise(q, k, v, i_pre, f_pre, *, chunk: int = 128,
             pltpu.VMEM((1, dh), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q.reshape(BH, S, dh), k.reshape(BH, S, dh), v.reshape(BH, S, dh),
       i_pre.reshape(BH, 1, S), f_pre.reshape(BH, 1, S))
     return out.reshape(B, H, S, dh)

@@ -1,9 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True because this container is CPU-only; on a
-real TPU pass interpret=False (the kernels are written against TPU tiling
-constraints: 128-lane blocks, MXU-aligned matmul dims, VMEM scratch
-accumulators).
+``interpret`` defaults to the platform (``repro.kernels.backend``):
+compiled on a TPU, interpreted on the CPU.  The kernels are written
+against TPU tiling constraints: 128-lane blocks, MXU-aligned matmul
+dims, VMEM scratch accumulators.
 """
 from repro.kernels.autotune import autotune_paged_decode
 from repro.kernels.decode_attention import decode_attention

@@ -49,7 +49,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import CompilerParams
+from repro.kernels.backend import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -145,10 +145,14 @@ def _step_kernel(pt_ref, len_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
 
     @pl.when(j == tj)
     def _append():
-        sel = jax.lax.iota(jnp.int32, bk) == (n1 % ps) % bk
-        ko_ref[0] = jnp.where(sel[:, None, None], kn_ref[0][None],
+        # the row mask is built at the block's full (bk, KVH, dh) shape and
+        # the new row broadcast along the major axis: Mosaic refuses the
+        # lane→major shape cast of a 1-D mask expanded with [:, None, None]
+        shape = k_ref.shape[1:]
+        sel = jax.lax.broadcasted_iota(jnp.int32, shape, 0) == (n1 % ps) % bk
+        ko_ref[0] = jnp.where(sel, jnp.broadcast_to(kn_ref[...], shape),
                               k_ref[0]).astype(ko_ref.dtype)
-        vo_ref[0] = jnp.where(sel[:, None, None], vn_ref[0][None],
+        vo_ref[0] = jnp.where(sel, jnp.broadcast_to(vn_ref[...], shape),
                               v_ref[0]).astype(vo_ref.dtype)
 
     @pl.when(j == n_blocks - 1)
@@ -172,7 +176,7 @@ def _step_kernel(pt_ref, len_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref,
                    static_argnames=("window", "block_k", "interpret"))
 def paged_decode_attention(q, k_pages, v_pages, page_table, lens, *,
                            window=None, block_k=None,
-                           interpret: bool = True):
+                           interpret: bool | None = None):
     """q: (B,H,dh); k/v_pages: (P,ps,KVH,dh); page_table: (B,MP) int32
     (-1 = unallocated, mapped to the trash page P-1 and masked);
     lens: (B,) int32 live token counts -> (B,H,dh).  A row with lens == 0
@@ -215,9 +219,9 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lens, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVH, g, dh), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(page_table, lens, q.reshape(B, KVH, g, dh), k_pages, v_pages)
     return out.reshape(B, H, dh)
 
@@ -226,7 +230,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lens, *,
                    static_argnames=("window", "block_k", "interpret"))
 def paged_decode_step(q, k_new, v_new, k_pages, v_pages, page_table,
                       lens, *, window=None, block_k=None,
-                      interpret: bool = True):
+                      interpret: bool | None = None):
     """Fused decode step: append k_new/v_new at position lens-1 of each
     slot's tail page AND attend over the sequence in one launch.
 
@@ -292,9 +296,9 @@ def paged_decode_step(q, k_new, v_new, k_pages, v_pages, page_table,
         # operand indices COUNT the scalar-prefetch operands:
         # (table, lens, q, k_new, v_new, k_pages, v_pages)
         input_output_aliases={5: 1, 6: 2},
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(page_table, lens, q.reshape(B, KVH, g, dh), k_new, v_new,
       k_pages, v_pages)
     return out.reshape(B, H, dh), k_out, v_out

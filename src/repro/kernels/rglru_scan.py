@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import CompilerParams
+from repro.kernels.backend import resolve_interpret
 
 
 def _kernel(a_ref, b_ref, h0_ref, o_ref, h_scr, *, bt: int):
@@ -40,7 +40,7 @@ def _kernel(a_ref, b_ref, h0_ref, o_ref, h_scr, *, bt: int):
 
 @functools.partial(jax.jit, static_argnames=("bt", "bd", "interpret"))
 def rglru_scan(a, b, h0, *, bt: int = 256, bd: int = 128,
-               interpret: bool = True):
+               interpret: bool | None = None):
     """a, b: (B,S,d); h0: (B,d) -> h: (B,S,d)."""
     B, S, d = a.shape
     bt = min(bt, S)
@@ -60,7 +60,7 @@ def rglru_scan(a, b, h0, *, bt: int = 256, bd: int = 128,
                                lambda ib, id_, it: (ib, it, id_)),
         out_shape=jax.ShapeDtypeStruct((B, S, d), a.dtype),
         scratch_shapes=[pltpu.VMEM((1, bd), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a, b, h0)

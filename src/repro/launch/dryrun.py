@@ -30,7 +30,7 @@ import jax           # noqa: E402
 
 from repro.configs import SHAPES, get_config, list_archs        # noqa: E402
 from repro.launch.hlo_cost import HloCost                       # noqa: E402
-from repro.launch.mesh import make_production_mesh, mesh_context  # noqa: E402
+from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.launch.specs import applicable, build_dryrun         # noqa: E402
 
 # ------------------------------- hardware constants (TPU v5e class) -------
@@ -73,7 +73,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
             cfg, shape, mesh,
             fsdp=(opts or {}).get("fsdp", fsdp), opts=opts)
         t0 = time.time()
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(fn, in_shardings=in_sh).lower(*args)
         t_lower = time.time() - t0
         t0 = time.time()

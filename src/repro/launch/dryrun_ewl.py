@@ -24,7 +24,7 @@ from repro.configs import get_config                          # noqa: E402
 from repro.distributed.pipeline import pipelined_forward      # noqa: E402
 from repro.launch.dryrun import (HBM_BW, LINK_BW, PEAK_FLOPS)  # noqa: E402
 from repro.launch.hlo_cost import HloCost                     # noqa: E402
-from repro.launch.mesh import make_production_mesh, mesh_context  # noqa: E402
+from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.launch.specs import batch_specs                    # noqa: E402
 from repro.models import init_params                          # noqa: E402
 
@@ -69,7 +69,7 @@ def main() -> None:
                                  axis="data")
 
     t0 = time.time()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(ewl_forward, in_shardings=(p_spec, None)
                           ).lower(params_sh, batch)
         compiled = lowered.compile()

@@ -4,30 +4,17 @@ Defined as FUNCTIONS so importing this module never touches jax device
 state.  The single-pod mesh is 16×16 = 256 chips (("data", "model"));
 multi-pod adds a leading "pod" axis: 2×16×16 = 512 chips.  The dry-run
 launcher force-creates 512 host devices BEFORE importing jax (see
-dryrun.py); everything else in the repo sees the real single device.
+dryrun.py); ``chip_smoke.py --chips 4`` lays ``make_test_mesh`` over the
+four chips of one host.
 """
 from __future__ import annotations
 
 import jax
 
 
-def mesh_context(mesh):
-    """``jax.set_mesh(mesh)`` where available (newer jax); sharded jit
-    carries the mesh through NamedShardings on older versions, so a
-    null context is equivalent there."""
-    import contextlib
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh else contextlib.nullcontext()
-
-
 def _make_mesh(shape, axes):
-    # jax.sharding.AxisType only exists on newer jax; older versions
-    # default every axis to Auto anyway.
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -37,8 +24,8 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_test_mesh(n_nodes: int = 8, axis: str = "node"):
-    """1-D mesh for λPipe multicast / pipeline tests on forced host
-    devices."""
+    """1-D mesh for λPipe multicast / pipeline: forced host devices in
+    tests, real chips in ``chip_smoke.py --chips 4``."""
     return _make_mesh((n_nodes,), (axis,))
 
 

@@ -5,6 +5,8 @@ calibrated simulator.
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-3b --requests 32
   PYTHONPATH=src python -m repro.launch.serve --continuous --requests 24
+  PYTHONPATH=src python -m repro.launch.serve --continuous --published \
+      --layers 18          # published widths, depth cut (needs a TPU)
   PYTHONPATH=src python -m repro.launch.serve --sim --model llama2-13b \
       --nodes 12 --rps 50
   PYTHONPATH=src python -m repro.launch.serve --live --nodes 8 --requests 12
@@ -18,12 +20,15 @@ calibrated simulator.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
 import numpy as np
 
 from repro.configs import get_config, reduced
+from repro.configs.base import ModelConfig
+from repro.launch.jax_cache import enable_compile_cache
 from repro.models import init_params, make_batch
 from repro.serving import ContinuousBatchingEngine, InferenceEngine
 from repro.serving.autoscaler import Autoscaler, AutoscalerConfig
@@ -47,8 +52,47 @@ def mixed_trace(n: int, prompt: int, tokens: int, seed: int = 0):
             for _ in range(n)]
 
 
+def device_label() -> str:
+    """Platform, device kind and count of the devices JAX runs on."""
+    devs = jax.devices()
+    return f"{devs[0].platform} {devs[0].device_kind} x{len(devs)}"
+
+
+def serve_config(args) -> ModelConfig:
+    """``--published``: the architecture at its published widths with
+    only the depth cut to ``--layers``; otherwise the reduced smoke
+    variant at ``--d-model``."""
+    cfg = get_config(args.arch)
+    if args.published:
+        return dataclasses.replace(cfg, n_layers=args.layers or cfg.n_layers)
+    return reduced(cfg, d_model=args.d_model, n_layers=args.layers,
+                   vocab=2048)
+
+
+def burst_requests(cfg: ModelConfig, trace, seed: int = 1):
+    """Random prompts of the trace's lengths: [(prompt ids, new tokens)]."""
+    rng = np.random.default_rng(seed)
+    return [(list(map(int, rng.integers(0, cfg.vocab_size, size=p))), t)
+            for p, t in trace]
+
+
+def serve_continuous(cfg: ModelConfig, params, requests, *, n_slots: int,
+                     max_len: int, attn_impl: str = "xla"):
+    """Serve ``requests`` as one burst (every request arrives at once,
+    slots refill mid-decode) on a paged ``ContinuousBatchingEngine``.
+    Returns (req_id -> generated ids, engine stats, wall seconds); the
+    wall time ends after the last token reached the host."""
+    eng = ContinuousBatchingEngine(cfg, params, n_slots=n_slots,
+                                   max_len=max_len, attn_impl=attn_impl)
+    for prompt, n_new in requests:
+        eng.submit(prompt, n_new)
+    t0 = time.perf_counter()
+    out = eng.run()
+    return out, eng.stats, time.perf_counter() - t0
+
+
 def run_engine(args) -> None:
-    cfg = reduced(get_config(args.arch), d_model=args.d_model, vocab=2048)
+    cfg = serve_config(args)
     params = init_params(cfg, jax.random.PRNGKey(0))
     eng = InferenceEngine(cfg, params, max_len=args.prompt + args.tokens)
     batch = make_batch(cfg, args.requests, args.prompt,
@@ -60,33 +104,25 @@ def run_engine(args) -> None:
     total = args.requests * args.tokens
     print(f"arch={cfg.arch_id}: served {args.requests} requests × "
           f"{args.tokens} tokens in {dt:.2f}s "
-          f"({total/dt:.1f} tok/s on CPU); output shape {out.shape}")
+          f"({total/dt:.1f} tok/s on {device_label()}); "
+          f"output shape {out.shape}")
 
 
 def run_continuous(args) -> None:
-    """Drive the continuous-batching engine through a mixed-length spike:
-    every request arrives at once (the burst), slots refill mid-decode."""
+    """Drive the continuous-batching engine through a mixed-length spike."""
     if args.requests < 1:
         raise SystemExit("--continuous needs --requests >= 1")
-    cfg = reduced(get_config(args.arch), d_model=args.d_model, vocab=2048)
+    cfg = serve_config(args)
     params = init_params(cfg, jax.random.PRNGKey(0))
     trace = mixed_trace(args.requests, args.prompt, args.tokens)
-    max_len = max(p + t for p, t in trace)
-    rng = np.random.default_rng(1)
-    prompts = [list(rng.integers(0, cfg.vocab_size, size=p)) for p, _ in trace]
-
-    eng = ContinuousBatchingEngine(cfg, params, n_slots=args.slots,
-                                   max_len=max_len)
-    for (plen, otok), prompt in zip(trace, prompts):
-        eng.submit(prompt, otok)
-    t0 = time.time()
-    out = eng.run()
-    dt = time.time() - t0
+    out, s, dt = serve_continuous(
+        cfg, params, burst_requests(cfg, trace), n_slots=args.slots,
+        max_len=max(p + t for p, t in trace))
     total = sum(len(v) for v in out.values())
-    s = eng.stats
-    print(f"arch={cfg.arch_id} continuous batching: {len(trace)} requests "
+    print(f"arch={cfg.arch_id} ({cfg.n_layers} layers, d_model "
+          f"{cfg.d_model}) continuous batching: {len(trace)} requests "
           f"({args.slots} slots) → {total} tokens in {dt:.2f}s "
-          f"({total/dt:.1f} tok/s on CPU)")
+          f"({total/dt:.1f} tok/s on {device_label()})")
     print(f"  prefills={s['prefills']} decode_ticks={s['decode_ticks']} "
           f"mean decode batch="
           f"{s['decode_tokens']/max(s['decode_ticks'],1):.2f}")
@@ -130,7 +166,8 @@ def run_live(args) -> None:
     adopted = sum(e.stats["adopted"] for m in ("A", "B")
                   for e in lc.serving[m].locals_.values())
     print(f"{args.requests} requests across 2 models → {total} tokens "
-          f"in {dt:.2f}s on CPU; {adopted} handed off mid-generation; "
+          f"in {dt:.2f}s on {device_label()}; {adopted} handed off "
+          f"mid-generation; "
           f"replicas: A={sorted(lc.serving['A'].locals_)} "
           f"B={sorted(lc.serving['B'].locals_)}")
 
@@ -252,7 +289,7 @@ def run_disagg(args) -> None:
     total = sum(len(v) for v in got.values())
     print(f"arch={cfg.arch_id} disagg: {n} requests → {total} tokens, "
           f"bit-equal to unified (unified {dt_u:.2f}s, disagg {dt_d:.2f}s "
-          f"on CPU)")
+          f"on {device_label()})")
     print(f"  prefill pool node 0: prefills={pre.stats['prefills']} "
           f"exported={pre.stats['exported']} "
           f"decode_ticks={pre.stats['decode_ticks']}")
@@ -431,6 +468,12 @@ def main() -> None:
                          "blocking fetch on the same probed trace")
     ap.add_argument("--arch", default="qwen2.5-3b")
     ap.add_argument("--d-model", type=int, default=256)
+    ap.add_argument("--published", action="store_true",
+                    help="published widths of --arch (engine and "
+                         "--continuous); only the depth is cut, by --layers")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="model depth (default: published depth with "
+                         "--published, the reduced depth otherwise)")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--prompt", type=int, default=64)
     ap.add_argument("--tokens", type=int, default=16)
@@ -440,6 +483,7 @@ def main() -> None:
     ap.add_argument("--rps", type=float, default=50.0)
     ap.add_argument("--duration", type=float, default=5.0)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.sim:
         run_sim(args)
     elif args.coldstart:

@@ -113,7 +113,7 @@ def test_fuzz_paged_attention(cases):
         table = _page_table(rng, B, P, MP, ps, lens)
         L = jnp.asarray(lens, jnp.int32)
         out = paged_decode_attention(q, k, v, table, L, window=window,
-                                     block_k=bk)
+                                     block_k=bk, interpret=True)
         ref = _paged_ref(q, k, v, table, L, window=window)
         np.testing.assert_allclose(
             np.asarray(out, np.float32), np.asarray(ref, np.float32),
@@ -145,7 +145,8 @@ def test_fuzz_paged_decode_step(cases):
         table = _page_table(rng, B, P, MP, ps, lens)
         L = jnp.asarray(lens, jnp.int32)
         out, ko, vo = paged_decode_step(q, kn, vn, k, v, table, L,
-                                        window=window, block_k=bk)
+                                        window=window, block_k=bk,
+                                        interpret=True)
         ref, kr, vr = _step_ref(q, kn, vn, k, v, table, L, window=window)
         msg = (f"step case={i} bucket={PAGED_BUCKETS[bidx]} block_k={bk} "
                f"lens={lens} seed={[BASE_SEED, 2, i]}\n"
@@ -190,7 +191,8 @@ def test_fuzz_decode_attention(cases):
                 spos[b, t % W] = t
             pos[b] = fill - 1
         out = decode_attention(q, k, v, jnp.asarray(spos),
-                               jnp.asarray(pos), window=window)
+                               jnp.asarray(pos), window=window,
+                               interpret=True)
         ref = _decode_ref(q, k, v, jnp.asarray(spos), jnp.asarray(pos),
                           window=window)
         np.testing.assert_allclose(
@@ -223,7 +225,7 @@ def test_fuzz_flash_attention(cases):
         k = jnp.asarray(rng.standard_normal((B, KVH, S, dh)), dtype)
         v = jnp.asarray(rng.standard_normal((B, KVH, S, dh)), dtype)
         out = flash_attention(q, k, v, causal=causal, window=window,
-                              bq=bq, bk=bk)
+                              bq=bq, bk=bk, interpret=True)
         ref = _flash_ref(q, k, v, causal=causal, window=window)
         np.testing.assert_allclose(
             np.asarray(out, np.float32), np.asarray(ref, np.float32),

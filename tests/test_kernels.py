@@ -31,7 +31,8 @@ def test_flash_attention_sweep(B, H, KVH, S, dh, causal, window, dtype):
     q = jnp.asarray(RNG.standard_normal((B, H, S, dh)), dtype)
     k = jnp.asarray(RNG.standard_normal((B, KVH, S, dh)), dtype)
     v = jnp.asarray(RNG.standard_normal((B, KVH, S, dh)), dtype)
-    out = flash_attention(q, k, v, causal=causal, window=window)
+    out = flash_attention(q, k, v, causal=causal, window=window,
+                          interpret=True)
     ref = flash_attention_ref(q, k, v, causal=causal, window=window)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
@@ -54,7 +55,8 @@ def test_decode_attention_sweep(B, H, KVH, W, dh, window, fill, dtype):
         slots[:, t % W] = t
     spos = jnp.asarray(slots)
     pos = jnp.full((B,), fill - 1, jnp.int32)
-    out = decode_attention(q, k, v, spos, pos, window=window)
+    out = decode_attention(q, k, v, spos, pos, window=window,
+                           interpret=True)
     ref = decode_attention_ref(q, k, v, spos, pos, window=window)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
@@ -72,7 +74,7 @@ def test_rglru_scan_sweep(B, S, d, bt, dtype):
     a = jnp.asarray(RNG.uniform(0.7, 0.999, (B, S, d)), dtype)
     b = jnp.asarray(RNG.standard_normal((B, S, d)) * 0.1, dtype)
     h0 = jnp.asarray(RNG.standard_normal((B, d)), jnp.float32)
-    out = rglru_scan(a, b, h0, bt=bt)
+    out = rglru_scan(a, b, h0, bt=bt, interpret=True)
     ref = rglru_scan_ref(a.astype(jnp.float32), b.astype(jnp.float32), h0)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
@@ -92,7 +94,8 @@ def test_flash_attention_matches_model_attention():
     ref = _sdpa(q, k, v, mask, None)
     out = flash_attention(q.transpose(0, 2, 1, 3),
                           k.transpose(0, 2, 1, 3),
-                          v.transpose(0, 2, 1, 3), causal=True)
+                          v.transpose(0, 2, 1, 3), causal=True,
+                          interpret=True)
     np.testing.assert_allclose(np.asarray(out.transpose(0, 2, 1, 3)),
                                np.asarray(ref), atol=2e-5, rtol=2e-5)
 
@@ -111,7 +114,8 @@ def test_mlstm_chunkwise_sweep(B, H, S, dh, chunk, dtype):
     v = jnp.asarray(RNG.standard_normal((B, H, S, dh)), dtype)
     i_pre = jnp.asarray(RNG.standard_normal((B, H, S)), jnp.float32)
     f_pre = jnp.asarray(RNG.standard_normal((B, H, S)) + 3.0, jnp.float32)
-    out = mlstm_chunkwise(q, k, v, i_pre, f_pre, chunk=chunk)
+    out = mlstm_chunkwise(q, k, v, i_pre, f_pre, chunk=chunk,
+                          interpret=True)
     ref = mlstm_chunkwise_ref(q.astype(jnp.float32),
                               k.astype(jnp.float32),
                               v.astype(jnp.float32), i_pre, f_pre,

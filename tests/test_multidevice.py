@@ -63,7 +63,7 @@ print("PIPELINE-OK")
 MINI_DRYRUN = r"""
 import jax, jax.numpy as jnp
 from repro.configs import get_config, reduced, SHAPES
-from repro.launch.mesh import _make_mesh, mesh_context
+from repro.launch.mesh import _make_mesh
 from repro.launch.specs import build_dryrun
 import dataclasses
 
@@ -73,7 +73,7 @@ shape = dataclasses.replace(SHAPES["train_4k"], seq_len=128, global_batch=8)
 for arch in ("qwen2.5-3b", "qwen2-moe-a2.7b"):
     cfg = reduced(get_config(arch))
     fn, args, in_sh = build_dryrun(cfg, shape, mesh)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(fn, in_shardings=in_sh).lower(*args).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes > 0
@@ -81,7 +81,7 @@ for arch in ("qwen2.5-3b", "qwen2-moe-a2.7b"):
     dshape = dataclasses.replace(SHAPES["decode_32k"], seq_len=256,
                                  global_batch=8)
     fn, args, in_sh = build_dryrun(cfg, dshape, mesh)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(fn, in_shardings=in_sh).lower(*args).compile()
 print("MINIDRYRUN-OK")
 """

@@ -409,7 +409,8 @@ def test_fused_append_matches_host_scatter_bytes(lens):
     k = jnp.asarray(rng.standard_normal((P, ps, KVH, dh)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((P, ps, KVH, dh)), jnp.float32)
 
-    _, ko, vo = paged_decode_step(q, kn, vn, k, v, table, L)
+    _, ko, vo = paged_decode_step(q, kn, vn, k, v, table, L,
+                                  interpret=True)
 
     n1 = np.maximum(np.asarray(lens) - 1, 0)
     pg = table_np[np.arange(B), np.minimum(n1 // ps, MP - 1)]

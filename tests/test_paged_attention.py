@@ -48,7 +48,8 @@ def test_paged_kernel_matches_ref(B, H, KVH, dh, ps, MP, window, lens,
     v = jnp.asarray(RNG.standard_normal((P, ps, KVH, dh)), dtype)
     table = _page_setup(B, P, MP, ps, lens)
     lens = jnp.asarray(lens, jnp.int32)
-    out = paged_decode_attention(q, k, v, table, lens, window=window)
+    out = paged_decode_attention(q, k, v, table, lens, window=window,
+                                 interpret=True)
     ref = paged_decode_attention_ref(q, k, v, table, lens, window=window)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
@@ -87,7 +88,8 @@ def test_paged_ref_matches_dense_decode_ref():
     np.testing.assert_allclose(np.asarray(paged), np.asarray(dense),
                                atol=2e-5, rtol=2e-5)
     out = paged_decode_attention(q, k_pages, v_pages, table,
-                                 jnp.asarray(lens, jnp.int32))
+                                 jnp.asarray(lens, jnp.int32),
+                                 interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(dense),
                                atol=2e-5, rtol=2e-5)
 
@@ -113,7 +115,8 @@ def test_fused_step_free_slot_writes_only_trash_page():
     table = _page_setup(B, P + 1, MP, ps, lens)  # P-1 stays unallocated
     table = jnp.where(table >= P - 1, -1, table)
 
-    _, ko, vo = paged_decode_step(q, kn, vn, k, v, table, step_lens)
+    _, ko, vo = paged_decode_step(q, kn, vn, k, v, table, step_lens,
+                                  interpret=True)
 
     # pages a correct kernel may touch: each live slot's tail page + trash
     allowed = {P - 1}

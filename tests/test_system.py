@@ -83,8 +83,8 @@ def test_train_launcher_runs():
          "stablelm-1.6b", "--steps", "3", "--batch", "2", "--seq", "64",
          "--d-model", "128"],
         env={"PYTHONPATH": SRC, "PATH": "/usr/bin:/bin",
-             # without the platform pin jax probes for accelerator
-             # backends and hangs on hosts with a TPU runtime
+             # the child runs on the parent's platform: the suite
+             # runs on the CPU backend (JAX_PLATFORMS=cpu)
              **({"JAX_PLATFORMS": os.environ["JAX_PLATFORMS"]}
                 if "JAX_PLATFORMS" in os.environ else {})},
         capture_output=True, text=True, timeout=300)
@@ -97,8 +97,8 @@ def test_serve_launcher_runs():
         [sys.executable, "-m", "repro.launch.serve", "--requests", "2",
          "--prompt", "16", "--tokens", "4", "--d-model", "128"],
         env={"PYTHONPATH": SRC, "PATH": "/usr/bin:/bin",
-             # without the platform pin jax probes for accelerator
-             # backends and hangs on hosts with a TPU runtime
+             # the child runs on the parent's platform: the suite
+             # runs on the CPU backend (JAX_PLATFORMS=cpu)
              **({"JAX_PLATFORMS": os.environ["JAX_PLATFORMS"]}
                 if "JAX_PLATFORMS" in os.environ else {})},
         capture_output=True, text=True, timeout=300)

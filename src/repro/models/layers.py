@@ -15,6 +15,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.models.scopes import ATTN_KV, ATTN_PROJ
+
 Params = Dict[str, Any]
 
 NEG_INF = -1e30
@@ -90,6 +92,7 @@ def init_attention(cfg, key, dtype) -> Params:
     return p
 
 
+@jax.named_scope(ATTN_PROJ)
 def _qkv(p, x, cfg, positions, rope: bool):
     B, S, _ = x.shape
     h, kv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
@@ -105,6 +108,16 @@ def _qkv(p, x, cfg, positions, rope: bool):
         q = apply_rope(q, positions, cfg)
         k = apply_rope(k, positions, cfg)
     return q, k, v
+
+
+@jax.named_scope(ATTN_PROJ)
+def _out_proj(p, o, cfg):
+    """Heads (B,S,H,dh) back to the model width through ``wo``."""
+    B, S = o.shape[0], o.shape[1]
+    out = o.reshape(B, S, cfg.n_heads * cfg.d_head) @ p["wo"]
+    if cfg.out_bias:
+        out = out + p["bo"]
+    return out
 
 
 def _gqa_scores(q, k):
@@ -145,10 +158,19 @@ def full_attention(p, x, cfg, positions, *, causal=True, rope=True,
         causal = False
     else:
         q, k, v = _qkv(p, x, cfg, positions, rope)
+    with jax.named_scope(ATTN_KV):
+        o = _attend_full(q, k, v, cfg, positions, causal, q_chunk, window,
+                         kv_override is not None)
+    return _out_proj(p, o, cfg), (k, v)
+
+
+def _attend_full(q, k, v, cfg, positions, causal, q_chunk, window, cross):
+    """Scores and values of ``full_attention``, query-chunked."""
+    B, S = q.shape[0], q.shape[1]
     Sk = k.shape[1]
     nchunk = max(1, S // q_chunk) if S % q_chunk == 0 else 1
     if nchunk <= 1:
-        kpos = positions if kv_override is None else \
+        kpos = positions if not cross else \
             jnp.broadcast_to(jnp.arange(Sk)[None], (B, Sk))
         mask = (positions[:, :, None] >= kpos[:, None, :]) if causal else \
             jnp.ones((B, S, Sk), bool)
@@ -172,10 +194,7 @@ def full_attention(p, x, cfg, positions, *, causal=True, rope=True,
 
         _, oc = jax.lax.scan(body, None, (qc.swapaxes(0, 1), pc.swapaxes(0, 1)))
         o = oc.swapaxes(0, 1).reshape(B, S, cfg.n_heads, cfg.d_head)
-    out = o.reshape(B, S, cfg.n_heads * cfg.d_head) @ p["wo"]
-    if cfg.out_bias:
-        out = out + p["bo"]
-    return out, (k, v)
+    return o
 
 
 # ------------------------------------------------------------- decode caches
@@ -195,7 +214,7 @@ def decode_attention(p, x, cache, cfg, positions, *, rope=True,
     """Single-token decode. x: (B,1,d); positions: (B,) int32.
     Returns (out (B,1,d), new_cache)."""
     B = x.shape[0]
-    h, kv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
+    h, dh = cfg.n_heads, cfg.d_head
     pos2 = positions[:, None]                              # (B,1)
     if cross_kv is not None:
         q = x @ p["wq"]
@@ -205,28 +224,25 @@ def decode_attention(p, x, cache, cfg, positions, *, rope=True,
         k, v = cross_kv
         Sk = k.shape[1]
         mask = jnp.ones((B, 1, Sk), bool)
-        o = _sdpa(q, k, v, mask, cfg)
-        out = o.reshape(B, 1, h * dh) @ p["wo"]
-        if cfg.out_bias:
-            out = out + p["bo"]
-        return out, cache
+        with jax.named_scope(ATTN_KV):
+            o = _sdpa(q, k, v, mask, cfg)
+        return _out_proj(p, o, cfg), cache
     q, k_new, v_new = _qkv(p, x, cfg, pos2, rope)          # (B,1,·,dh)
-    W = cache["k"].shape[1]
-    slot = positions % W                                   # (B,)
-    bidx = jnp.arange(B)
-    k = cache["k"].at[bidx, slot].set(k_new[:, 0])
-    v = cache["v"].at[bidx, slot].set(v_new[:, 0])
-    spos = cache["pos"].at[bidx, slot].set(positions)
-    valid = (spos >= 0) & (spos <= pos2)                   # (B,W)
-    if window is not None:
-        valid &= pos2 - spos < window
-    o = _sdpa(q, k, v, valid[:, None, :], cfg)
-    out = o.reshape(B, 1, h * dh) @ p["wo"]
-    if cfg.out_bias:
-        out = out + p["bo"]
-    return out, {"k": k, "v": v, "pos": spos}
+    with jax.named_scope(ATTN_KV):
+        W = cache["k"].shape[1]
+        slot = positions % W                               # (B,)
+        bidx = jnp.arange(B)
+        k = cache["k"].at[bidx, slot].set(k_new[:, 0])
+        v = cache["v"].at[bidx, slot].set(v_new[:, 0])
+        spos = cache["pos"].at[bidx, slot].set(positions)
+        valid = (spos >= 0) & (spos <= pos2)               # (B,W)
+        if window is not None:
+            valid &= pos2 - spos < window
+        o = _sdpa(q, k, v, valid[:, None, :], cfg)
+    return _out_proj(p, o, cfg), {"k": k, "v": v, "pos": spos}
 
 
+@jax.named_scope(ATTN_KV)
 def paged_page_context(page_table, positions, ps: int, P: int,
                        windows=(None,)):
     """Precompute the per-tick page-table expansions every attention
@@ -273,10 +289,20 @@ def paged_decode_attention(p, x, pool, cfg, positions, page_table, *,
     the pools donated in place.  ``page_ctx`` (``paged_page_context``)
     carries the tick-level table expansions so the XLA path does no
     per-layer table work.  Returns (out (B,1,d), new_pool)."""
-    B = x.shape[0]
-    h, kv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
-    pos2 = positions[:, None]                              # (B,1)
-    q, k_new, v_new = _qkv(p, x, cfg, pos2, rope)          # (B,1,·,dh)
+    q, k_new, v_new = _qkv(p, x, cfg, positions[:, None], rope)
+    with jax.named_scope(ATTN_KV):
+        o, k_pool, v_pool = _paged_decode_kv(
+            q, k_new, v_new, pool, positions, page_table, cfg, window,
+            impl, block_k, page_ctx)
+    return _out_proj(p, o, cfg), {"k": k_pool, "v": v_pool}
+
+
+def _paged_decode_kv(q, k_new, v_new, pool, positions, page_table, cfg,
+                     window, impl, block_k, page_ctx):
+    """The new token's K/V into its page, then attention over the
+    slot's pages: ``paged_decode_attention`` between its projections."""
+    B = q.shape[0]
+    kv, dh = cfg.n_kv_heads, cfg.d_head
     P, ps = pool["k"].shape[0], pool["k"].shape[1]
     MP = page_table.shape[1]
     if impl == "pallas":
@@ -284,22 +310,16 @@ def paged_decode_attention(p, x, pool, cfg, positions, page_table, *,
         o, k_pool, v_pool = paged_decode_step(
             q[:, 0], k_new[:, 0], v_new[:, 0], pool["k"], pool["v"],
             page_table, positions + 1, window=window, block_k=block_k)
-        o = o[:, None]
-    else:
-        if page_ctx is None:
-            page_ctx = paged_page_context(page_table, positions, ps, P,
-                                          windows=(window,))
-        k_pool = pool["k"].at[page_ctx["pg"], page_ctx["off"]].set(
-            k_new[:, 0])
-        v_pool = pool["v"].at[page_ctx["pg"], page_ctx["off"]].set(
-            v_new[:, 0])
-        kg = k_pool[page_ctx["pt"]].reshape(B, MP * ps, kv, dh)
-        vg = v_pool[page_ctx["pt"]].reshape(B, MP * ps, kv, dh)
-        o = _sdpa(q, kg, vg, page_ctx["valid"][window][:, None, :], cfg)
-    out = o.reshape(B, 1, h * dh) @ p["wo"]
-    if cfg.out_bias:
-        out = out + p["bo"]
-    return out, {"k": k_pool, "v": v_pool}
+        return o[:, None], k_pool, v_pool
+    if page_ctx is None:
+        page_ctx = paged_page_context(page_table, positions, ps, P,
+                                      windows=(window,))
+    k_pool = pool["k"].at[page_ctx["pg"], page_ctx["off"]].set(k_new[:, 0])
+    v_pool = pool["v"].at[page_ctx["pg"], page_ctx["off"]].set(v_new[:, 0])
+    kg = k_pool[page_ctx["pt"]].reshape(B, MP * ps, kv, dh)
+    vg = v_pool[page_ctx["pt"]].reshape(B, MP * ps, kv, dh)
+    o = _sdpa(q, kg, vg, page_ctx["valid"][window][:, None, :], cfg)
+    return o, k_pool, v_pool
 
 
 def paged_suffix_attention(p, x, pool, cfg, positions, pt_row, *,
@@ -317,32 +337,30 @@ def paged_suffix_attention(p, x, pool, cfg, positions, pt_row, *,
     outputs are bit-identical to a full prefill that recomputed the
     prefix (same summation order; masked tail entries underflow to
     exact zeros).  Returns (out (1,S,d), new_pool)."""
-    B, S, _ = x.shape
-    h, kv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
+    kv, dh = cfg.n_kv_heads, cfg.d_head
     q, k_new, v_new = _qkv(p, x, cfg, positions, rope)     # (1,S,·,dh)
     P, ps = pool["k"].shape[0], pool["k"].shape[1]
     MP = pt_row.shape[0]
-    tpos = positions[0]                                    # (S,)
-    pg = pt_row[jnp.clip(tpos // ps, 0, MP - 1)]
-    pg = jnp.where(pg >= 0, pg, P - 1)                     # FREE → trash
-    k_pool = pool["k"].at[pg, tpos % ps].set(k_new[0])
-    v_pool = pool["v"].at[pg, tpos % ps].set(v_new[0])
-    pt = jnp.where(pt_row >= 0, pt_row, P - 1)
-    kg = k_pool[pt].reshape(1, MP * ps, kv, dh)
-    vg = v_pool[pt].reshape(1, MP * ps, kv, dh)
-    t = jnp.arange(MP * ps)[None, None, :]
-    qpos = positions[:, :, None]                           # (1,S,1)
-    valid = (t <= qpos) & \
-        (jnp.repeat(pt_row, ps) >= 0)[None, None, :]
-    if window is not None:
-        valid &= qpos - t < window
-    o = _sdpa(q, kg, vg, valid, cfg)
-    out = o.reshape(B, S, h * dh) @ p["wo"]
-    if cfg.out_bias:
-        out = out + p["bo"]
-    return out, {"k": k_pool, "v": v_pool}
+    with jax.named_scope(ATTN_KV):
+        tpos = positions[0]                                # (S,)
+        pg = pt_row[jnp.clip(tpos // ps, 0, MP - 1)]
+        pg = jnp.where(pg >= 0, pg, P - 1)                 # FREE → trash
+        k_pool = pool["k"].at[pg, tpos % ps].set(k_new[0])
+        v_pool = pool["v"].at[pg, tpos % ps].set(v_new[0])
+        pt = jnp.where(pt_row >= 0, pt_row, P - 1)
+        kg = k_pool[pt].reshape(1, MP * ps, kv, dh)
+        vg = v_pool[pt].reshape(1, MP * ps, kv, dh)
+        t = jnp.arange(MP * ps)[None, None, :]
+        qpos = positions[:, :, None]                       # (1,S,1)
+        valid = (t <= qpos) & \
+            (jnp.repeat(pt_row, ps) >= 0)[None, None, :]
+        if window is not None:
+            valid &= qpos - t < window
+        o = _sdpa(q, kg, vg, valid, cfg)
+    return _out_proj(p, o, cfg), {"k": k_pool, "v": v_pool}
 
 
+@jax.named_scope(ATTN_KV)
 def kv_cache_from_prefill(cfg, k, v, positions, max_len, *, window=None):
     """Convert full-sequence prefill K/V (B,S,kv,dh) into a decode cache."""
     B, S = k.shape[0], k.shape[1]

@@ -27,6 +27,7 @@ from repro.configs.base import ModelConfig
 from repro.models import layers as L
 from repro.models import moe as M
 from repro.models import recurrent as R
+from repro.models import scopes
 from repro.models import xlstm as X
 
 Params = Dict[str, Any]
@@ -123,6 +124,12 @@ def init_params(cfg: ModelConfig, key, dtype=jnp.float32) -> Params:
 
 
 # ============================================================ layer (full)
+@jax.named_scope(scopes.MLP)
+def _mlp(p: Params, x, cfg: ModelConfig):
+    """The dense feed-forward block on the normed residual."""
+    return L.apply_ffn(p["ffn"], L.apply_norm(p["norm2"], x, cfg), cfg)
+
+
 def _apply_layer_full(p: Params, x, cfg: ModelConfig, entry: str, positions,
                       *, enc_out=None, build_cache=False, cache_len=None,
                       moe_cf=1.25):
@@ -173,7 +180,7 @@ def _apply_layer_full(p: Params, x, cfg: ModelConfig, entry: str, positions,
         if build_cache:
             cache = st
     if ffn == "dense":
-        x = x + L.apply_ffn(p["ffn"], L.apply_norm(p["norm2"], x, cfg), cfg)
+        x = x + _mlp(p, x, cfg)
     elif ffn == "moe":
         mo, a = M.apply_moe(p["moe"], L.apply_norm(p["norm2"], x, cfg), cfg,
                             capacity_factor=moe_cf)
@@ -231,7 +238,7 @@ def _apply_layer_decode(p: Params, x, cfg: ModelConfig, entry: str,
         out, new_cache = X.apply_slstm_step(p["slstm"], h, cfg, cache)
         x = x + out
     if ffn == "dense":
-        x = x + L.apply_ffn(p["ffn"], L.apply_norm(p["norm2"], x, cfg), cfg)
+        x = x + _mlp(p, x, cfg)
     elif ffn == "moe":
         mo, _ = M.apply_moe(p["moe"], L.apply_norm(p["norm2"], x, cfg), cfg,
                             capacity_factor=None)
@@ -251,8 +258,7 @@ def _encode(cfg: ModelConfig, enc_p: Params, frames) -> jnp.ndarray:
         a, _ = L.full_attention(lp["attn"], h, cfg, positions,
                                 causal=False, rope=False)
         xc = xc + a
-        xc = xc + L.apply_ffn(lp["ffn"],
-                              L.apply_norm(lp["norm2"], xc, cfg), cfg)
+        xc = xc + _mlp(lp, xc, cfg)
         return xc, None
 
     x, _ = jax.lax.scan(body, x, enc_p["layers"])
@@ -260,6 +266,7 @@ def _encode(cfg: ModelConfig, enc_p: Params, frames) -> jnp.ndarray:
 
 
 # ================================================================== embed
+@jax.named_scope(scopes.EMBED)
 def _embed_tokens(cfg: ModelConfig, params: Params, tokens, positions,
                   patches=None):
     x = params["embed"][tokens]
@@ -273,6 +280,7 @@ def _embed_tokens(cfg: ModelConfig, params: Params, tokens, positions,
     return x
 
 
+@jax.named_scope(scopes.LM_HEAD)
 def _unembed(cfg: ModelConfig, params: Params, x):
     x = L.apply_norm(params["final_norm"], x, cfg)
     if cfg.tie_embeddings:
@@ -534,6 +542,7 @@ def _page_targets(spos, pt_row, page_size, n_pool_pages):
     return pg, spos % page_size
 
 
+@jax.named_scope(scopes.ATTN_KV)
 def paged_prefill_scatter(cfg: ModelConfig, cache, single_cache, slot,
                           pt_row, n_tokens=None):
     """Scatter a freshly-built batch-1 (ring-layout) decode cache into
@@ -631,7 +640,7 @@ def _apply_layer_suffix(p: Params, x, cfg: ModelConfig, entry: str,
         rope=cfg.rope_pct > 0.0, window=_mixer_window(cfg, mixer))
     x = x + a
     if ffn == "dense":
-        x = x + L.apply_ffn(p["ffn"], L.apply_norm(p["norm2"], x, cfg), cfg)
+        x = x + _mlp(p, x, cfg)
     elif ffn == "moe":
         mo, _ = M.apply_moe(p["moe"], L.apply_norm(p["norm2"], x, cfg), cfg,
                             capacity_factor=None)

@@ -39,9 +39,11 @@ from repro.models import (DEFAULT_PAGE_SIZE, PackedKV, PageTable,
                           paged_adopt_scatter, paged_copy_page,
                           paged_geometry, paged_pack,
                           paged_prefill_scatter, paged_suffix_prefill,
-                          pages_for, supports_prefix_sharing)
+                          pages_for, scopes, supports_prefix_sharing)
+from repro.serving import trace
 from repro.serving.scheduler import (DEFAULT_SLOTS, AdmissionPolicy,
                                      Scheduler, SeqState, SlotState)
+from repro.serving.trace import span
 
 # wire-dedupe export tag: every handoff() export of a prefix-sharing
 # engine gets a fresh batch id, shared by all its payloads, so adopters
@@ -105,6 +107,11 @@ class InferenceEngine:
 
 
 # ===================================================== continuous batching
+@jax.named_scope(scopes.LM_HEAD)
+def _greedy(logits):
+    return jnp.argmax(logits, -1).astype(jnp.int32)
+
+
 @functools.lru_cache(maxsize=None)
 def _cb_executables(cfg: ModelConfig, max_len: int):
     """Jitted (prefill+scatter, decode+argmax) shared across every engine
@@ -122,14 +129,14 @@ def _cb_executables(cfg: ModelConfig, max_len: int):
     def prefill_scatter(params, pool, last_tok, tokens, slot):
         out = forward(cfg, params, {"tokens": tokens}, build_cache=True,
                       cache_len=max_len, moe_cf=None)
-        first = jnp.argmax(out["logits"][:, -1], -1).astype(jnp.int32)
+        first = _greedy(out["logits"][:, -1])
         last_tok = jax.lax.dynamic_update_slice(last_tok, first, (slot,))
         return last_tok, cache_scatter(pool, out["cache"], slot, axes)
 
     def step(params, cache, last_tok):
         logits, cache = decode_step(cfg, params, cache, last_tok,
                                     cache["pos"])
-        return jnp.argmax(logits, -1).astype(jnp.int32), cache
+        return _greedy(logits), cache
 
     return jax.jit(prefill_scatter), jax.jit(step), axes
 
@@ -148,7 +155,7 @@ def _paged_executables(cfg: ModelConfig, max_len: int, page_size: int,
     def prefill_scatter(params, cache, last_tok, tokens, slot):
         out = forward(cfg, params, {"tokens": tokens}, build_cache=True,
                       cache_len=max_len, moe_cf=None)
-        first = jnp.argmax(out["logits"][:, -1], -1).astype(jnp.int32)
+        first = _greedy(out["logits"][:, -1])
         last_tok = jax.lax.dynamic_update_slice(last_tok, first, (slot,))
         pt_row = cache["pages"][slot]
         # tokens.shape[1] is static per prompt length (one executable
@@ -161,7 +168,7 @@ def _paged_executables(cfg: ModelConfig, max_len: int, page_size: int,
         logits, cache = decode_step(cfg, params, cache, last_tok,
                                     cache["pos"], attn_impl=attn_impl,
                                     block_k=block_k, ctx_pages=mp)
-        return jnp.argmax(logits, -1).astype(jnp.int32), cache
+        return _greedy(logits), cache
 
     def suffix_prefill(params, cache, last_tok, tokens, slot, start):
         # prefix sharing: the slot's leading pages already hold ``start``
@@ -170,7 +177,7 @@ def _paged_executables(cfg: ModelConfig, max_len: int, page_size: int,
         # length, like prefill_scatter per prompt length.
         logits, cache = paged_suffix_prefill(cfg, params, cache, tokens,
                                              slot, start)
-        first = jnp.argmax(logits, -1).astype(jnp.int32)
+        first = _greedy(logits)
         last_tok = jax.lax.dynamic_update_slice(last_tok, first, (slot,))
         return last_tok, cache
 
@@ -299,6 +306,8 @@ class ContinuousBatchingEngine:
         # (req_id, slo_class_name, retry_after) of submits the scheduler
         # rejected outright; drained by take_shed()
         self.shed_log: List[Tuple[int, str, float]] = []
+        # count, total and longest host seconds of each span (trace.NAMES)
+        self.phases = trace.Phases()
 
     # ------------------------------------------------------------- intake
     def submit(self, prompt: Sequence[int], max_new_tokens: int, *,
@@ -324,10 +333,11 @@ class ContinuousBatchingEngine:
                 f"({self.n_pages} × {self.page_size} tokens)")
         if eos_id is not None:
             self._eager = True
-        res = self.sched.submit(SeqState(req_id, list(prompt),
-                                         max_new_tokens, eos_id=eos_id,
-                                         t_arrive=t_arrive, slo=slo,
-                                         probe=probe))
+        with span(self.phases, trace.SUBMIT, req_id=req_id):
+            res = self.sched.submit(SeqState(req_id, list(prompt),
+                                             max_new_tokens, eos_id=eos_id,
+                                             t_arrive=t_arrive, slo=slo,
+                                             probe=probe))
         if res.shed:
             self.shed_log.append((req_id,
                                   slo.name if slo is not None else "",
@@ -347,10 +357,13 @@ class ContinuousBatchingEngine:
         """Resolve placeholder token ids (single blocking gather)."""
         if not self._pending:
             return
-        arrs = jax.device_get([a for _, _, _, a in self._pending])
-        for (seq, idx, slot, _), vals in zip(self._pending, arrs):
-            seq.generated[idx] = int(vals[slot])
-        self._pending = []
+        with span(self.phases, trace.FLUSH):
+            with span(self.phases, trace.FLUSH_WAIT):
+                arrs = jax.device_get([a for _, _, _, a in self._pending])
+            with span(self.phases, trace.FLUSH_RESOLVE):
+                for (seq, idx, slot, _), vals in zip(self._pending, arrs):
+                    seq.generated[idx] = int(vals[slot])
+                self._pending = []
 
     def _do_prefill(self, slot: int, seq: SeqState) -> None:
         toks = seq.tokens_so_far
@@ -528,66 +541,80 @@ class ContinuousBatchingEngine:
 
     def step(self) -> bool:
         """Run one scheduler tick.  Returns False when nothing ran."""
-        # un-harvested preemption victims (standalone engine — no
-        # cluster parked them to the host tier last tick) re-enter the
-        # resume queue now, AFTER the preempting requester was admitted
-        if self.preempt_outbox:
-            self.adopt([(s, p) for s, p, _ in self.preempt_outbox])
-            self.preempt_outbox = []
-        self._maybe_preempt()
-        tick = self.sched.next_tick()
-        # a parked sequence that finished while parked (EOS in its last
-        # handed-off token) is retired by the scheduler without ever
-        # taking a slot — drop the cache it was parked with
-        if self._parked:
-            for rid in [r for r in self._parked if r in self.sched.finished]:
-                self._dedupe_discard(rid, self._parked.pop(rid))
-        if tick.idle:
-            return False
-        # drop back to the sync-free path once no live/queued/parked
-        # sequence terminates on EOS (the latch would otherwise cost a
-        # host read per token for the rest of the engine's lifetime)
-        if self._eager and not any(
-                s is not None and s.eos_id is not None
-                for s in self.sched.slots) and not any(
-                s.eos_id is not None
-                for s in self.sched.queue + self.sched.resume_queue):
-            self._eager = False
-        # resumed sequences are mid-decode: their caches must land in the
-        # pool BEFORE this tick's decode step advances every row
-        for slot, seq in tick.resume:
-            self._restore(slot, seq, self._parked.pop(seq.req_id, None))
+        with span(self.phases, trace.STEP) as sp:
+            return self._tick(sp)
+
+    def _tick(self, sp: span) -> bool:
+        with span(self.phases, trace.SCHEDULE):
+            # un-harvested preemption victims (standalone engine — no
+            # cluster parked them to the host tier last tick) re-enter
+            # the resume queue now, AFTER the preempting requester was
+            # admitted
+            if self.preempt_outbox:
+                self.adopt([(s, p) for s, p, _ in self.preempt_outbox])
+                self.preempt_outbox = []
+            self._maybe_preempt()
+            tick = self.sched.next_tick()
+            # a parked sequence that finished while parked (EOS in its
+            # last handed-off token) is retired by the scheduler without
+            # ever taking a slot — drop the cache it was parked with
+            if self._parked:
+                for rid in [r for r in self._parked
+                            if r in self.sched.finished]:
+                    self._dedupe_discard(rid, self._parked.pop(rid))
+            if tick.idle:
+                return False
+            # drop back to the sync-free path once no live/queued/parked
+            # sequence terminates on EOS (the latch would otherwise cost
+            # a host read per token for the rest of the engine's life)
+            if self._eager and not any(
+                    s is not None and s.eos_id is not None
+                    for s in self.sched.slots) and not any(
+                    s.eos_id is not None
+                    for s in self.sched.queue + self.sched.resume_queue):
+                self._eager = False
+            # resumed sequences are mid-decode: their caches must land in
+            # the pool BEFORE this tick's decode step advances every row
+            for slot, seq in tick.resume:
+                self._restore(slot, seq, self._parked.pop(seq.req_id, None))
+        sp.set(tick=self.sched.tick_count, decoded=len(tick.decode),
+               admitted=len(tick.admit))
         # decode first: the pooled decode step advances EVERY cache row,
         # so freshly-prefilled rows must be scattered after it, not before
         # (their ignored pseudo-step would otherwise corrupt pos/KV).
         if tick.decode:
             if self.paged:
-                # the incoming token's page must exist before the jitted
-                # step writes K/V at position seq.pos - 1; the table
-                # rides into the call as a host operand when dirty so
-                # the upload overlaps the in-flight previous step
-                for slot in tick.decode:
-                    self.pages.ensure(slot, self.sched.slots[slot].pos)
-                self.cache["pages"] = self.pages.step_operand()
-                # bucket the step by the max allocated page count over
-                # LIVE slots (not just tick.decode — a resumed slot's
-                # row advances too): attention gathers/masks only those
-                # table columns, so work scales with live tokens
-                mp = max((max(s.pos - 1, 0) // self.page_size) + 1
-                         for s in self.sched.slots if s is not None)
-                self._last_tok, self.cache = self._step(
-                    self.params, self.cache, self._last_tok,
-                    mp=min(mp, self.max_pages))
-                self.pages.note_device(self.cache["pages"])
+                with span(self.phases, trace.PAGES):
+                    # the incoming token's page must exist before the
+                    # jitted step writes K/V at position seq.pos - 1; the
+                    # table rides into the call as a host operand when
+                    # dirty so the upload overlaps the in-flight previous
+                    # step
+                    for slot in tick.decode:
+                        self.pages.ensure(slot, self.sched.slots[slot].pos)
+                    self.cache["pages"] = self.pages.step_operand()
+                    # bucket the step by the max allocated page count over
+                    # LIVE slots (not just tick.decode — a resumed slot's
+                    # row advances too): attention gathers/masks only
+                    # those table columns, so work scales with live tokens
+                    mp = max((max(s.pos - 1, 0) // self.page_size) + 1
+                             for s in self.sched.slots if s is not None)
+                with span(self.phases, trace.DECODE):
+                    self._last_tok, self.cache = self._step(
+                        self.params, self.cache, self._last_tok,
+                        mp=min(mp, self.max_pages))
+                    self.pages.note_device(self.cache["pages"])
             else:
-                self._last_tok, self.cache = self._step(
-                    self.params, self.cache, self._last_tok)
+                with span(self.phases, trace.DECODE):
+                    self._last_tok, self.cache = self._step(
+                        self.params, self.cache, self._last_tok)
             for slot in tick.decode:
                 seq = self.sched.slots[slot]
                 self.sched.on_decoded(slot, self._record(seq, slot,
                                                          self._last_tok))
         for slot, seq in tick.admit:
-            self._do_prefill(slot, seq)
+            with span(self.phases, trace.PREFILL, req_id=seq.req_id):
+                self._do_prefill(slot, seq)
         return True
 
     def run(self) -> Dict[int, List[int]]:

@@ -50,6 +50,7 @@ from __future__ import annotations
 import dataclasses
 import enum
 import math
+import time
 from typing import TYPE_CHECKING, ClassVar, Dict, List, Optional, Tuple
 
 if TYPE_CHECKING:                                    # pragma: no cover
@@ -249,7 +250,13 @@ class SeqState:
     # report exactly the mode-switch path the paper measures)
     submit_tick: Optional[int] = None
     first_token_tick: Optional[int] = None
-    t_arrive: Optional[float] = None     # simulated-clock arrival (metrics)
+    # arrival on the SIMULATED clock of a timed replay (cluster, metrics,
+    # deadlines); never compared with the host-clock stamps below
+    t_arrive: Optional[float] = None
+    # host clock (time.perf_counter): first submission, kept across
+    # handoffs like submit_tick, and the latest admission into a slot
+    t_submit: Optional[float] = None
+    t_admit: Optional[float] = None
     slo: Optional["SLOClass"] = None     # service class (control plane)
     handoffs: int = 0
     # prompt tokens resolved from the prefix cache at admission
@@ -427,6 +434,7 @@ class Scheduler:
                            f">= shed_limit {self.shed_limit}")
         if seq.submit_tick is None:
             seq.submit_tick = self.tick_count
+            seq.t_submit = time.perf_counter()
         self.queue.append(seq)
         return SubmitResult(SubmitResult.OK)
 
@@ -585,6 +593,7 @@ class Scheduler:
                         prompt=self.queue[qi].prompt):
                     break        # the policy's head blocks: no size bypass
                 seq = self.queue.pop(qi)
+                seq.t_admit = time.perf_counter()
                 self.slots[slot] = seq
                 self.state[slot] = SlotState.PREFILL
                 if self.pages is not None:

@@ -13,6 +13,7 @@ this one file so that a single worker loads it.
 """
 import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +23,7 @@ from repro.configs import get_config
 from repro.kernels import backend
 from repro.kernels.paged_attention import (paged_decode_attention,
                                            paged_decode_step)
-from repro.models import init_paged_cache, init_params, pages_for
+from repro.models import init_paged_cache, init_params, pages_for, scopes
 from repro.serving.engine import _paged_executables
 
 # the usable HBM of one v5e chip as its compiler reports it ("15.75G")
@@ -136,6 +137,20 @@ def test_engine_decode_step_fits_v5e(one_chip, qwen, attn_impl,
     params, cache, last_tok = _engine_state(qwen, one_chip)
     text = _fits(step.lower(params, cache, last_tok).compile())
     assert ("tpu_custom_call" in text) == (attn_impl == "pallas")
+
+
+@pytest.mark.parametrize("attn_impl", ["xla", "pallas"])
+def test_engine_decode_step_names_its_scopes(one_chip, qwen, attn_impl,
+                                             monkeypatch):
+    """Each named scope of the model step reaches the compiled decode
+    step's op metadata, where a trace reader maps device ops to it."""
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    _, step, _, _ = _engine_programs(qwen, attn_impl)
+    params, cache, last_tok = _engine_state(qwen, one_chip)
+    text = step.lower(params, cache, last_tok).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in scopes.ALL:
+        assert any(f"/{scope}/" in n for n in names), scope
 
 
 def test_engine_prefill_fits_v5e(one_chip, qwen):

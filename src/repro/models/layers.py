@@ -270,13 +270,15 @@ def paged_page_context(page_table, positions, ps: int, P: int,
 def paged_decode_attention(p, x, pool, cfg, positions, page_table, *,
                            rope=True, window: Optional[int] = None,
                            impl: str = "xla", block_k: Optional[int] = None,
-                           page_ctx=None):
+                           page_ctx=None, layer=None):
     """Single-token decode against the shared page pool.
 
     x: (B,1,d) with B == n_slots; positions: (B,) int32;
-    pool: {"k","v"} of shape (P, page_size, kv, dh) where the LAST page
+    pool: {"k","v"} of shape (P, page_size, kv * dh) where the LAST page
     is the trash page (absorbs writes from FREE slots whose page-table
-    row is cleared); page_table: (B, MP) int32 page ids, -1 empty.
+    row is cleared), or with ``layer`` the stacked (reps, P, page_size,
+    kv * dh) pools of which this layer's is ``[layer]``; page_table:
+    (B, MP) int32 page ids, -1 empty.
 
     The new token's K/V land in the page covering ``positions`` (the
     engine guarantees it is allocated for live slots), then attention
@@ -285,51 +287,65 @@ def paged_decode_attention(p, x, pool, cfg, positions, page_table, *,
     pooled path, so greedy tokens match the striped cache bit-for-bit
     when page_size divides the pool width.  ``impl="pallas"`` runs the
     FUSED decode-step kernel (``repro.kernels.paged_attention.
-    paged_decode_step``): append + gather + softmax in one launch with
-    the pools donated in place.  ``page_ctx`` (``paged_page_context``)
-    carries the tick-level table expansions so the XLA path does no
-    per-layer table work.  Returns (out (B,1,d), new_pool)."""
+    paged_decode_step``): append + gather + softmax in one launch on
+    this layer's pool, reshaped to the kernel's (P, page_size, kv, dh).
+    ``page_ctx`` (``paged_page_context``) carries the tick-level table
+    expansions so the XLA path does no per-layer table work.  Returns
+    (out (B,1,d), new_pool)."""
     q, k_new, v_new = _qkv(p, x, cfg, positions[:, None], rope)
     with jax.named_scope(ATTN_KV):
         o, k_pool, v_pool = _paged_decode_kv(
             q, k_new, v_new, pool, positions, page_table, cfg, window,
-            impl, block_k, page_ctx)
+            impl, block_k, page_ctx, layer)
     return _out_proj(p, o, cfg), {"k": k_pool, "v": v_pool}
 
 
 def _paged_decode_kv(q, k_new, v_new, pool, positions, page_table, cfg,
-                     window, impl, block_k, page_ctx):
+                     window, impl, block_k, page_ctx, layer):
     """The new token's K/V into its page, then attention over the
-    slot's pages: ``paged_decode_attention`` between its projections."""
+    slot's pages: ``paged_decode_attention`` between its projections.
+    The write and the gather index the stacked pool by ``layer``
+    directly (``pool[layer, pt]``, never ``pool[layer][pt]``), so no
+    layer's pool is sliced out."""
     B = q.shape[0]
     kv, dh = cfg.n_kv_heads, cfg.d_head
-    P, ps = pool["k"].shape[0], pool["k"].shape[1]
+    P, ps = pool["k"].shape[-3], pool["k"].shape[-2]
     MP = page_table.shape[1]
+    at = () if layer is None else (layer,)
     if impl == "pallas":
         from repro.kernels.paged_attention import paged_decode_step
+        heads = [(a if layer is None else a[layer]).reshape(P, ps, kv, dh)
+                 for a in (pool["k"], pool["v"])]
         o, k_pool, v_pool = paged_decode_step(
-            q[:, 0], k_new[:, 0], v_new[:, 0], pool["k"], pool["v"],
+            q[:, 0], k_new[:, 0], v_new[:, 0], *heads,
             page_table, positions + 1, window=window, block_k=block_k)
-        return o[:, None], k_pool, v_pool
+        new = [a.reshape(P, ps, kv * dh) for a in (k_pool, v_pool)]
+        if layer is not None:
+            new = [a.at[layer].set(n)
+                   for a, n in zip((pool["k"], pool["v"]), new)]
+        return o[:, None], new[0], new[1]
     if page_ctx is None:
         page_ctx = paged_page_context(page_table, positions, ps, P,
                                       windows=(window,))
-    k_pool = pool["k"].at[page_ctx["pg"], page_ctx["off"]].set(k_new[:, 0])
-    v_pool = pool["v"].at[page_ctx["pg"], page_ctx["off"]].set(v_new[:, 0])
-    kg = k_pool[page_ctx["pt"]].reshape(B, MP * ps, kv, dh)
-    vg = v_pool[page_ctx["pt"]].reshape(B, MP * ps, kv, dh)
+    tgt = at + (page_ctx["pg"], page_ctx["off"])
+    k_pool = pool["k"].at[tgt].set(k_new[:, 0].reshape(B, kv * dh))
+    v_pool = pool["v"].at[tgt].set(v_new[:, 0].reshape(B, kv * dh))
+    kg = k_pool[at + (page_ctx["pt"],)].reshape(B, MP * ps, kv, dh)
+    vg = v_pool[at + (page_ctx["pt"],)].reshape(B, MP * ps, kv, dh)
     o = _sdpa(q, kg, vg, page_ctx["valid"][window][:, None, :], cfg)
     return o, k_pool, v_pool
 
 
 def paged_suffix_attention(p, x, pool, cfg, positions, pt_row, *,
-                           rope=True, window: Optional[int] = None):
+                           rope=True, window: Optional[int] = None,
+                           layer=None):
     """Suffix-only prefill against the shared page pool (prefix sharing).
 
     x: (1,S,d) — the un-cached suffix of one prompt whose shared prefix
     K/V already sit in the slot's leading pages; positions: (1,S)
     absolute token positions (shared_tokens + arange(S)); pt_row: (MP,)
-    the slot's page-table row.  The suffix K/V are scattered into the
+    the slot's page-table row; pool and ``layer`` as in
+    ``paged_decode_attention``.  The suffix K/V are scattered into the
     slot's pages at their (page, offset) targets, then every suffix
     query attends over the slot's whole table expansion — shared prefix
     pages and just-written suffix alike — masked to its own causal
@@ -339,15 +355,18 @@ def paged_suffix_attention(p, x, pool, cfg, positions, pt_row, *,
     exact zeros).  Returns (out (1,S,d), new_pool)."""
     kv, dh = cfg.n_kv_heads, cfg.d_head
     q, k_new, v_new = _qkv(p, x, cfg, positions, rope)     # (1,S,·,dh)
-    P, ps = pool["k"].shape[0], pool["k"].shape[1]
+    P, ps = pool["k"].shape[-3], pool["k"].shape[-2]
     MP = pt_row.shape[0]
+    S = x.shape[1]
+    at = () if layer is None else (layer,)
     with jax.named_scope(ATTN_KV):
         tpos = positions[0]                                # (S,)
         pg = pt_row[jnp.clip(tpos // ps, 0, MP - 1)]
         pg = jnp.where(pg >= 0, pg, P - 1)                 # FREE → trash
-        k_pool = pool["k"].at[pg, tpos % ps].set(k_new[0])
-        v_pool = pool["v"].at[pg, tpos % ps].set(v_new[0])
-        pt = jnp.where(pt_row >= 0, pt_row, P - 1)
+        tgt = at + (pg, tpos % ps)
+        k_pool = pool["k"].at[tgt].set(k_new[0].reshape(S, kv * dh))
+        v_pool = pool["v"].at[tgt].set(v_new[0].reshape(S, kv * dh))
+        pt = at + (jnp.where(pt_row >= 0, pt_row, P - 1),)
         kg = k_pool[pt].reshape(1, MP * ps, kv, dh)
         vg = v_pool[pt].reshape(1, MP * ps, kv, dh)
         t = jnp.arange(MP * ps)[None, None, :]

@@ -193,14 +193,16 @@ def _apply_layer_full(p: Params, x, cfg: ModelConfig, entry: str, positions,
 def _apply_layer_decode(p: Params, x, cfg: ModelConfig, entry: str,
                         positions, cache, *, page_table=None,
                         attn_impl: str = "xla", block_k=None,
-                        page_ctx=None):
+                        page_ctx=None, layer=None):
     """Single-token layer application. x: (B,1,d); positions (B,).
 
     ``page_table`` switches attention layers to the paged pool layout
     (``cache`` then holds {"k","v"} page pools instead of per-slot
-    stripes); non-attention state stays slot-indexed either way.
-    ``page_ctx`` is the tick-level table expansion shared by every
-    paged layer (hoisted out of the trunk scan by ``decode_step``)."""
+    stripes; with ``layer`` they are the whole stacked pools and this
+    layer's is ``[layer]``); non-attention state stays slot-indexed
+    either way.  ``page_ctx`` is the tick-level table expansion shared
+    by every paged layer (hoisted out of the trunk scan by
+    ``decode_step``)."""
     mixer, ffn = entry.split(":")
     rope = cfg.rope_pct > 0.0
     h = L.apply_norm(p["norm1"], x, cfg)
@@ -212,7 +214,7 @@ def _apply_layer_decode(p: Params, x, cfg: ModelConfig, entry: str,
             a, new_self = L.paged_decode_attention(
                 p["attn"], h, self_cache, cfg, positions, page_table,
                 rope=rope, window=win, impl=attn_impl, block_k=block_k,
-                page_ctx=page_ctx)
+                page_ctx=page_ctx, layer=layer)
         else:
             a, new_self = L.decode_attention(p["attn"], h, self_cache, cfg,
                                              positions, rope=rope,
@@ -345,7 +347,7 @@ def _paged_pool_dims(cfg: ModelConfig, cache):
         if _is_paged_entry(entry):
             leaf = (cache["trunk"] if where == "trunk"
                     else cache["rem"])[i]["k"]
-            return leaf.shape[-4], leaf.shape[-3]
+            return leaf.shape[-3], leaf.shape[-2]
     return None
 
 
@@ -361,7 +363,11 @@ def decode_step(cfg: ModelConfig, params: Params, cache, tokens, positions,
     indices, write target, validity mask per distinct window) is
     computed ONCE here and threaded through the trunk scan — it is
     loop-invariant, so hoisting it keeps the per-layer work at the
-    attention math itself.  ``block_k`` tunes the Pallas fused kernel's
+    attention math itself.  The stacked page pools ride the scan's
+    carry, not its inputs and outputs: each layer writes its token into
+    and gathers its pages from the one stacked buffer by layer index,
+    so a donated pool is updated in place instead of sliced per layer
+    and restacked.  ``block_k`` tunes the Pallas fused kernel's
     sub-page KV block (``attn_impl="pallas"``; autotuned via
     ``repro.kernels.autotune``).
 
@@ -373,7 +379,6 @@ def decode_step(cfg: ModelConfig, params: Params, cache, tokens, positions,
     Every live token sits inside those pages by construction and FREE
     rows stay ``-1`` → trash page, so outputs are bit-identical to the
     full-table walk."""
-    B = tokens.shape[0]
     page_table = cache.get("pages")
     ctx_table = page_table
     if (page_table is not None and ctx_pages is not None
@@ -391,31 +396,41 @@ def decode_step(cfg: ModelConfig, params: Params, cache, tokens, positions,
                                             windows=wins)
     pos2 = positions[:, None]
     x = _embed_tokens(cfg, params, tokens[:, None], pos2)
+    layer_kw = dict(page_table=ctx_table, attn_impl=attn_impl,
+                    block_k=block_k, page_ctx=page_ctx)
+    # paged pools are carried whole; other layer state is scanned
+    carried = tuple(
+        c if page_table is not None and _is_paged_entry(entry) else None
+        for entry, c in zip(cfg.layer_pattern, cache["trunk"]))
+    scanned = tuple(None if p is not None else c
+                    for p, c in zip(carried, cache["trunk"]))
 
-    def rep_body(xc, xs):
-        lp_tuple, c_tuple = xs
-        new_caches = []
+    def rep_body(carry, xs):
+        xc, pools = carry
+        lp_tuple, c_tuple, li = xs
+        pools, new_caches = list(pools), []
         for pi, entry in enumerate(cfg.layer_pattern):
-            xc, nc = _apply_layer_decode(lp_tuple[pi], xc, cfg, entry,
-                                         positions, c_tuple[pi],
-                                         page_table=ctx_table,
-                                         attn_impl=attn_impl,
-                                         block_k=block_k,
-                                         page_ctx=page_ctx)
-            new_caches.append(nc)
-        return xc, tuple(new_caches)
+            if pools[pi] is not None:
+                xc, pools[pi] = _apply_layer_decode(
+                    lp_tuple[pi], xc, cfg, entry, positions, pools[pi],
+                    layer=li, **layer_kw)
+                new_caches.append(None)
+            else:
+                xc, nc = _apply_layer_decode(lp_tuple[pi], xc, cfg, entry,
+                                             positions, c_tuple[pi],
+                                             **layer_kw)
+                new_caches.append(nc)
+        return (xc, tuple(pools)), tuple(new_caches)
 
-    x, new_trunk = jax.lax.scan(rep_body, x,
-                                (params["trunk"], cache["trunk"]))
+    (x, pools), ys = jax.lax.scan(
+        rep_body, (x, carried),
+        (params["trunk"], scanned, jnp.arange(cfg.n_pattern_reps)))
+    new_trunk = tuple(p if p is not None else c for p, c in zip(pools, ys))
     new_rem = []
     for ri, lp in enumerate(params["rem"]):
         entry = cfg.layer_pattern[ri % cfg.pattern_len]
         x, nc = _apply_layer_decode(lp, x, cfg, entry, positions,
-                                    cache["rem"][ri],
-                                    page_table=ctx_table,
-                                    attn_impl=attn_impl,
-                                    block_k=block_k,
-                                    page_ctx=page_ctx)
+                                    cache["rem"][ri], **layer_kw)
         new_rem.append(nc)
     logits = _unembed(cfg, params, x)[:, 0]
     out_cache = {"trunk": new_trunk, "rem": tuple(new_rem),
@@ -468,8 +483,24 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.float32):
 # Attention K/V live in a shared pool of fixed-size token pages addressed
 # through one per-slot page table (shared by every attention layer — the
 # same token occupies the same page slot in each layer's pool, so one
-# allocation covers the whole stack).  Non-attention state (RG-LRU /
-# xLSTM) is O(d) per slot, not O(tokens), and stays slot-indexed.
+# allocation covers the whole stack).  A page stores its tokens' heads
+# side by side, (page_size, kv * dh): the minor dimension is a whole
+# number of 128-lane tiles whenever kv * dh is a multiple of 128, so the
+# TPU keeps the pool row-major and unpadded even where dh alone is not.
+# Heads are split out again only on gathered pages and on the PackedKV
+# wire, which stays (..., page_size, kv, dh).  Non-attention state
+# (RG-LRU / xLSTM) is O(d) per slot, not O(tokens), and stays
+# slot-indexed.
+def _lanes(x):
+    """(..., kv, dh) → the pool's (..., kv * dh)."""
+    return x.reshape(x.shape[:-2] + (-1,))
+
+
+def _heads(cfg: ModelConfig, x):
+    """The pool's (..., kv * dh) → (..., kv, dh)."""
+    return x.reshape(x.shape[:-1] + (cfg.n_kv_heads, cfg.d_head))
+
+
 def _is_paged_entry(entry: str) -> bool:
     return entry.split(":")[0] in ("attn", "attn_full")
 
@@ -487,9 +518,10 @@ def init_paged_cache(cfg: ModelConfig, n_slots: int, *,
                      max_pages: Optional[int] = None,
                      max_len: Optional[int] = None, dtype=jnp.float32,
                      attn_impl: str = "xla"):
-    """Zeroed paged decode cache: per-layer page pools carry ONE extra
-    trash page (index n_pages) that absorbs writes from FREE slots, and
-    the top level holds the shared device page table.
+    """Zeroed paged decode cache: per-layer page pools of shape
+    (n_pages + 1, page_size, kv * dh) carry ONE extra trash page (index
+    n_pages) that absorbs writes from FREE slots, and the top level
+    holds the shared device page table.
 
     ``page_size`` may be ``"auto"`` (requires ``max_len``): the pool
     geometry is resolved through ``cache_ops.paged_geometry``, which
@@ -514,12 +546,12 @@ def init_paged_cache(cfg: ModelConfig, n_slots: int, *,
         if n_pages is None:
             n_pages = n_slots * max_pages
     reps = cfg.n_pattern_reps
-    kv, dh = cfg.n_kv_heads, cfg.d_head
+    shape = (n_pages + 1, page_size, cfg.n_kv_heads * cfg.d_head)
 
     def one(entry):
         if _is_paged_entry(entry):
-            return {"k": jnp.zeros((n_pages + 1, page_size, kv, dh), dtype),
-                    "v": jnp.zeros((n_pages + 1, page_size, kv, dh), dtype)}
+            return {"k": jnp.zeros(shape, dtype),
+                    "v": jnp.zeros(shape, dtype)}
         return _init_layer_cache(cfg, entry, n_slots, 0, dtype)
 
     trunk = []
@@ -566,8 +598,7 @@ def paged_prefill_scatter(cfg: ModelConfig, cache, single_cache, slot,
         src = (single_cache["trunk"] if where == "trunk"
                else single_cache["rem"])[i]
         if _is_paged_entry(entry):
-            ps = dst["k"].shape[-3]
-            P = dst["k"].shape[-4] if where == "rem" else dst["k"].shape[1]
+            P, ps = dst["k"].shape[-3], dst["k"].shape[-2]
             MP = pt_row.shape[0]
             W = src["k"].shape[-3]
             if W == MP * ps:
@@ -583,27 +614,27 @@ def paged_prefill_scatter(cfg: ModelConfig, cache, single_cache, slot,
                 pg = jnp.where(pt_row >= 0, pt_row, P - 1)[:npg]
                 if where == "trunk":
                     reps = src["k"].shape[0]
-                    kv_dims = src["k"].shape[3:]
                     pages = lambda leaf: leaf[:, 0].reshape(
-                        (reps, MP, ps) + kv_dims)[:, :npg]
+                        (reps, MP, ps, -1))[:, :npg]
                     upd = {"k": dst["k"].at[:, pg].set(pages(src["k"])),
                            "v": dst["v"].at[:, pg].set(pages(src["v"]))}
                 else:
-                    kv_dims = src["k"].shape[2:]
                     pages = lambda leaf: leaf[0].reshape(
-                        (MP, ps) + kv_dims)[:npg]
+                        (MP, ps, -1))[:npg]
                     upd = {"k": dst["k"].at[pg].set(pages(src["k"])),
                            "v": dst["v"].at[pg].set(pages(src["v"]))}
             elif where == "trunk":
                 spos = src["pos"][0, 0]                       # (W,)
                 pg, off = _page_targets(spos, pt_row, ps, P)
-                upd = {"k": dst["k"].at[:, pg, off].set(src["k"][:, 0]),
-                       "v": dst["v"].at[:, pg, off].set(src["v"][:, 0])}
+                upd = {"k": dst["k"].at[:, pg, off].set(
+                           _lanes(src["k"][:, 0])),
+                       "v": dst["v"].at[:, pg, off].set(
+                           _lanes(src["v"][:, 0]))}
             else:
                 spos = src["pos"][0]
                 pg, off = _page_targets(spos, pt_row, ps, P)
-                upd = {"k": dst["k"].at[pg, off].set(src["k"][0]),
-                       "v": dst["v"].at[pg, off].set(src["v"][0])}
+                upd = {"k": dst["k"].at[pg, off].set(_lanes(src["k"][0])),
+                       "v": dst["v"].at[pg, off].set(_lanes(src["v"][0]))}
         else:
             ax = 1 if where == "trunk" else 0
             upd = jax.tree.map(
@@ -628,7 +659,7 @@ def supports_prefix_sharing(cfg: ModelConfig) -> bool:
 
 
 def _apply_layer_suffix(p: Params, x, cfg: ModelConfig, entry: str,
-                        positions, pool, pt_row):
+                        positions, pool, pt_row, layer=None):
     """Suffix-prefill layer application (prefix sharing; attention-only
     configs — ``supports_prefix_sharing`` gates callers)."""
     mixer, ffn = entry.split(":")
@@ -637,7 +668,8 @@ def _apply_layer_suffix(p: Params, x, cfg: ModelConfig, entry: str,
     h = L.apply_norm(p["norm1"], x, cfg)
     a, new_pool = L.paged_suffix_attention(
         p["attn"], h, pool, cfg, positions, pt_row,
-        rope=cfg.rope_pct > 0.0, window=_mixer_window(cfg, mixer))
+        rope=cfg.rope_pct > 0.0, window=_mixer_window(cfg, mixer),
+        layer=layer)
     x = x + a
     if ffn == "dense":
         x = x + _mlp(p, x, cfg)
@@ -657,7 +689,8 @@ def paged_suffix_prefill(cfg: ModelConfig, params: Params, cache, tokens,
     token count, so positions run start..start+S-1.  Each layer scatters
     the suffix K/V into the slot's pages and attends suffix queries over
     the slot's full table — causal masking makes prefix activations
-    depend only on the prefix, so skipping its recompute is exact.
+    depend only on the prefix, so skipping its recompute is exact.  The
+    stacked pools ride the scan's carry, as in ``decode_step``.
     Returns (last-position logits (1, V), new paged cache); compute
     scales with the suffix, not the prompt."""
     S = tokens.shape[1]
@@ -665,17 +698,19 @@ def paged_suffix_prefill(cfg: ModelConfig, params: Params, cache, tokens,
     positions = (start + jnp.arange(S, dtype=jnp.int32))[None]
     x = _embed_tokens(cfg, params, tokens, positions)
 
-    def rep_body(xc, xs):
-        lp_tuple, c_tuple = xs
-        new_caches = []
+    def rep_body(carry, xs):
+        xc, pools = carry
+        lp_tuple, li = xs
+        pools = list(pools)
         for pi, entry in enumerate(cfg.layer_pattern):
-            xc, nc = _apply_layer_suffix(lp_tuple[pi], xc, cfg, entry,
-                                         positions, c_tuple[pi], pt_row)
-            new_caches.append(nc)
-        return xc, tuple(new_caches)
+            xc, pools[pi] = _apply_layer_suffix(
+                lp_tuple[pi], xc, cfg, entry, positions, pools[pi], pt_row,
+                layer=li)
+        return (xc, tuple(pools)), None
 
-    x, new_trunk = jax.lax.scan(rep_body, x,
-                                (params["trunk"], cache["trunk"]))
+    (x, new_trunk), _ = jax.lax.scan(
+        rep_body, (x, cache["trunk"]),
+        (params["trunk"], jnp.arange(cfg.n_pattern_reps)))
     new_rem = []
     for ri, lp in enumerate(params["rem"]):
         entry = cfg.layer_pattern[ri % cfg.pattern_len]
@@ -726,12 +761,14 @@ def paged_pack(cfg: ModelConfig, cache, slot: int, page_ids,
     for where, i, entry in _layer_entries(cfg):
         src = (cache["trunk"] if where == "trunk" else cache["rem"])[i]
         if _is_paged_entry(entry):
-            assert src["k"].shape[-3] == page_size, \
+            assert src["k"].shape[-2] == page_size, \
                 (src["k"].shape, page_size)
             if where == "trunk":
-                out = {"k": src["k"][:, ids], "v": src["v"][:, ids]}
+                out = {"k": _heads(cfg, src["k"][:, ids]),
+                       "v": _heads(cfg, src["v"][:, ids])}
             else:
-                out = {"k": src["k"][ids], "v": src["v"][ids]}
+                out = {"k": _heads(cfg, src["k"][ids]),
+                       "v": _heads(cfg, src["v"][ids])}
         else:
             ax = 1 if where == "trunk" else 0
             out = jax.tree.map(
@@ -754,16 +791,9 @@ def paged_adopt_scatter(cfg: ModelConfig, cache, packed, slot: int,
         dst = trunk[i] if where == "trunk" else rem[i]
         src = packed.kv["trunk" if where == "trunk" else "rem"][i]
         if _is_paged_entry(entry):
-            if where == "trunk":
-                upd = {"k": dst["k"].at[:, ids].set(
-                           src["k"].astype(dst["k"].dtype)),
-                       "v": dst["v"].at[:, ids].set(
-                           src["v"].astype(dst["v"].dtype))}
-            else:
-                upd = {"k": dst["k"].at[ids].set(
-                           src["k"].astype(dst["k"].dtype)),
-                       "v": dst["v"].at[ids].set(
-                           src["v"].astype(dst["v"].dtype))}
+            at = (slice(None), ids) if where == "trunk" else ids
+            upd = {n: dst[n].at[at].set(_lanes(src[n]).astype(dst[n].dtype))
+                   for n in ("k", "v")}
         else:
             ax = 1 if where == "trunk" else 0
             upd = jax.tree.map(

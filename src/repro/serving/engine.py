@@ -23,8 +23,11 @@ switching hands its live slot state to this engine via
 """
 from __future__ import annotations
 
+import copy
 import functools
+import inspect
 import itertools
+import weakref
 from typing import (TYPE_CHECKING, Any, Dict, List, Optional, Sequence,
                     Tuple)
 
@@ -141,16 +144,87 @@ def _cb_executables(cfg: ModelConfig, max_len: int):
     return jax.jit(prefill_scatter), jax.jit(step), axes
 
 
+def _split_pool(cache) -> Tuple[Dict[str, Any], Dict[str, Any]]:
+    """(layer state, the rest) of a paged cache: the trunk and remainder
+    state a program updates in place, and the page table and positions,
+    which callers may keep (the page table's device copy is reused)."""
+    rest = dict(cache)
+    return {"trunk": rest.pop("trunk"), "rem": rest.pop("rem")}, rest
+
+
+class _InPlace:
+    """A paged program ``fn`` jitted to update the KV pool in place.
+
+    Argument ``argnum`` of ``fn`` is a paged cache.  Its layer state
+    (the page pools) is donated, so XLA writes the result's pools into
+    the same buffers and the caller's pool arrays are deleted; its page
+    table and positions are not.  Calls and ``lower`` take the cache
+    whole, as ``fn`` does.  ``bound(engine)`` gives the same program
+    tied to the engine whose pool it updates: a call on a cache holding
+    that engine's pool leaves the engine holding the result's pool,
+    whether or not the caller keeps the result."""
+
+    def __init__(self, fn, argnum: int, static_argnames=()):
+        def split(*args, **kw):
+            state, rest = args[argnum], args[argnum + 1]
+            return fn(*args[:argnum], {**rest, **state},
+                      *args[argnum + 2:], **kw)
+
+        # the program keeps ``fn``'s name (``jit_step`` in a trace) and
+        # its parameters' names, which name the compiled program's
+        # inputs (the trace readers find weights by them)
+        split.__name__ = split.__qualname__ = fn.__name__
+        sig = inspect.signature(fn)
+        ps = list(sig.parameters.values())
+        rest = ps[argnum].replace(name=f"{ps[argnum].name}_rest")
+        split.__signature__ = sig.replace(
+            parameters=ps[:argnum + 1] + [rest] + ps[argnum + 1:])
+        self.argnum = argnum
+        self._jit = jax.jit(split, donate_argnums=argnum,
+                            static_argnames=static_argnames)
+        self._owner = None
+
+    def _args(self, args):
+        a = self.argnum
+        return args[:a] + _split_pool(args[a]) + args[a + 1:]
+
+    def __call__(self, *args, **kw):
+        out = self._jit(*self._args(args), **kw)
+        eng = self._owner() if self._owner is not None else None
+        if eng is not None and args[self.argnum]["trunk"] is \
+                eng.cache["trunk"]:
+            state, _ = _split_pool(out[-1] if isinstance(out, tuple)
+                                   else out)
+            eng.cache = {**eng.cache, **state}
+        return out
+
+    def lower(self, *args, **kw):
+        return self._jit.lower(*self._args(args), **kw)
+
+    def bound(self, engine) -> "_InPlace":
+        b = copy.copy(self)
+        b._owner = weakref.ref(engine)
+        return b
+
+
+# where each program of ``_paged_executables`` takes the cache, and its
+# static arguments.  ``mp`` is static: one decode executable per
+# live-page-count bucket (≤ max_pages of them), so attention work tracks
+# live tokens.
+_PAGED_SIGNATURES = ((1, ()), (1, ("mp",)), (1, ()), (0, ()))
+
+
 @functools.lru_cache(maxsize=None)
 def _paged_executables(cfg: ModelConfig, max_len: int, page_size: int,
                        n_pages: int, max_pages: int, attn_impl: str,
                        block_k=None):
-    """Jitted (prefill+page-scatter, paged decode+argmax) shared across
-    engines of the same pool geometry — the paged analogue of
-    ``_cb_executables``.  The page table rides inside the cache pytree,
-    so allocation changes between ticks never recompile.  ``block_k``
-    tunes the fused Pallas kernel's sub-page KV block (autotuner
-    output; the XLA path ignores it)."""
+    """Jitted (prefill+page-scatter, paged decode+argmax, suffix
+    prefill, page copy) shared across engines of the same pool geometry
+    — the paged analogue of ``_cb_executables``.  Each updates the pool
+    in place (``_InPlace``).  The page table rides inside the cache
+    pytree, so allocation changes between ticks never recompile.
+    ``block_k`` tunes the fused Pallas kernel's sub-page KV block
+    (autotuner output; the XLA path ignores it)."""
 
     def prefill_scatter(params, cache, last_tok, tokens, slot):
         out = forward(cfg, params, {"tokens": tokens}, build_cache=True,
@@ -185,11 +259,9 @@ def _paged_executables(cfg: ModelConfig, max_len: int, page_size: int,
         # copy-on-write fork: duplicate pool page src into dst
         return paged_copy_page(cfg, cache, src, dst)
 
-    # ``mp`` is static: one executable per live-page-count bucket
-    # (≤ max_pages of them), so attention work tracks live tokens
-    return (jax.jit(prefill_scatter),
-            jax.jit(step, static_argnames=("mp",)),
-            jax.jit(suffix_prefill), jax.jit(copy_page))
+    return tuple(_InPlace(fn, *sig) for fn, sig in zip(
+        (prefill_scatter, step, suffix_prefill, copy_page),
+        _PAGED_SIGNATURES))
 
 
 class ContinuousBatchingEngine:
@@ -267,10 +339,16 @@ class ContinuousBatchingEngine:
                 cfg, n_slots, n_pages=self.n_pages, page_size=page_size,
                 max_pages=self.max_pages)
             self.cache["pages"] = self.pages.device_table()
+            # a program handed over unjitted (a wrapper around one of
+            # ours, say) is jitted here, so the pool is still donated
+            # at the outermost call
             (self._prefill_scatter, self._step, self._suffix_prefill,
-             self._copy_page) = _paged_executables(
-                cfg, max_len, page_size, self.n_pages, self.max_pages,
-                attn_impl, self.block_k)
+             self._copy_page) = (
+                (p if isinstance(p, _InPlace) else _InPlace(p, *sig))
+                .bound(self)
+                for p, sig in zip(_paged_executables(
+                    cfg, max_len, page_size, self.n_pages, self.max_pages,
+                    attn_impl, self.block_k), _PAGED_SIGNATURES))
             self._axes = None
         else:
             self.pages = None

@@ -289,16 +289,31 @@ def test_preempt_resume_on_second_engine_bit_equal():
     _assert_drained(eng2)
 
 
-def test_standalone_engine_auto_preempts_and_self_readopts():
+@pytest.mark.parametrize("prefix", [0, 28], ids=["distinct", "shared"])
+def test_standalone_engine_auto_preempts_and_self_readopts(prefix,
+                                                           monkeypatch):
     """preemption=True without a cluster: an interactive arrival evicts
     a batch slot this very tick, and the victim re-enters through the
-    engine's own outbox → resume queue next step — nothing is lost."""
+    engine's own outbox → resume queue next step — nothing is lost.
+    With a shared prefix that ends mid-page, sharers skip its prefill
+    and fork the partial page (copy-on-write) before writing, and the
+    victim ships as deduped PackedKV: every paged program runs on the
+    donated pool along the way."""
     eng = _engine(n_slots=2, preemption=True,
                   policy=StrictPriorityPolicy())
+    forks = []
+    fork = eng.pages.fork
+
+    def counted(slot, index):
+        old, new = fork(slot, index)
+        forks.append(old != new)
+        return old, new
+    monkeypatch.setattr(eng.pages, "fork", counted)
+    shared = _toks(19, prefix)
     prompts = {}
     for i, (slo, n_tok) in enumerate([(BATCH, 10), (BATCH, 10),
                                       (INTERACTIVE, 4)]):
-        p = _toks(20 + i, 6)
+        p = shared + _toks(20 + i, 6)
         rid = eng.submit(p, n_tok, slo=slo, t_arrive=float(i))
         prompts[rid] = (p, n_tok)
         if i == 1:
@@ -315,6 +330,8 @@ def test_standalone_engine_auto_preempts_and_self_readopts():
     assert set(fin) == set(prompts)
     for rid, (p, n_tok) in prompts.items():
         assert fin[rid].generated == _reference(p, n_tok), rid
+    assert (eng.sched.stats["shared_tokens"] > 0) == bool(prefix)
+    assert any(forks) == bool(prefix)
     _assert_drained(eng)
 
 

@@ -143,6 +143,34 @@ def test_paged_engine_matches_pooled_and_static():
         assert outs[True][i] == _reference(prompts[i], n), f"req {i}"
 
 
+def test_engine_programs_update_the_pool_in_place():
+    """Each paged program donates the pool: after a step the cache the
+    engine held before has its pool arrays deleted (page table and
+    positions survive), and a program run on the engine's cache whose
+    result the caller drops, as a warm-up does, leaves the engine
+    holding the new pool; tokens stay equal to the reference."""
+    cfg, params, _ = _ctx()
+    eng = ContinuousBatchingEngine(cfg, params, n_slots=2, max_len=MAX_LEN,
+                                   page_size=PAGE_SIZE)
+    prompt = _prompt(800, 9)
+    eng.submit(prompt, 5, req_id=0)
+    for _ in range(3):
+        before = eng.cache
+        assert eng.step()
+        pools = jax.tree.leaves((before["trunk"], before["rem"]))
+        assert pools and all(x.is_deleted() for x in pools)
+        assert not before["pos"].is_deleted()
+    assert eng.run()[0] == _reference(prompt, 5)
+    held = eng.cache
+    eng._step(eng.params, dict(eng.cache, pages=eng.pages.device_table()),
+              eng._last_tok, mp=1)
+    assert all(x.is_deleted() for x in jax.tree.leaves(held["trunk"]))
+    assert not any(x.is_deleted() for x in jax.tree.leaves(eng.cache))
+    prompt = _prompt(801, 7)
+    eng.submit(prompt, 6, req_id=1)
+    assert eng.run()[1] == _reference(prompt, 6)
+
+
 def test_paged_pool_undersized_throttles_but_stays_exact():
     """A pool with fewer pages than slots×max_pages admits by page
     budget: requests queue instead of corrupting each other, and every

@@ -13,7 +13,10 @@ this one file so that a single worker loads it.
 """
 import dataclasses
 import functools
+import json
+import os
 import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +28,12 @@ from repro.kernels.paged_attention import (paged_decode_attention,
                                            paged_decode_step)
 from repro.models import init_paged_cache, init_params, pages_for, scopes
 from repro.serving.engine import _paged_executables
+
+from conftest import REPO
+
+sys.path.insert(0, REPO)
+
+from chipbench import modelspec  # noqa: E402
 
 # the usable HBM of one v5e chip as its compiler reports it ("15.75G")
 V5E_HBM_BYTES = 15.75 * 2**30
@@ -160,3 +169,83 @@ def test_engine_prefill_fits_v5e(one_chip, qwen):
     tokens = _spec((1, 512), jnp.int32, one_chip)
     slot = _spec((), jnp.int32, one_chip)
     _fits(prefill.lower(params, cache, last_tok, tokens, slot).compile())
+
+
+# the chip benchmark's cells: (configuration, the largest decode bucket
+# ``mp`` the cell's traffic reaches)
+CELLS = {"stablelm-1.6b.longctx": ("stablelm-1.6b", 124),
+         "qwen2.5-3b.chat": ("qwen2.5-3b", 64)}
+
+
+def _cell_geometry(name):
+    """The cell's ``ModelConfig`` as the benchmark builds it, its serving
+    geometry, and its largest decode bucket."""
+    config, mp = CELLS[name]
+    with open(os.path.join(REPO, "chipbench", "configs",
+                           f"{config}.json")) as f:
+        spec = json.load(f)
+    return modelspec.model_config(spec), spec["serving"], mp
+
+
+def _cell_programs(name, sharding):
+    cfg, s, mp = _cell_geometry(name)
+    max_pages = pages_for(s["max_len"], s["page_size"])
+    programs = _paged_executables.__wrapped__(
+        cfg, s["max_len"], s["page_size"], s["n_slots"] * max_pages,
+        max_pages, "xla")
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    cache = jax.eval_shape(functools.partial(
+        init_paged_cache, cfg, s["n_slots"], page_size=s["page_size"],
+        max_len=s["max_len"]))
+    last_tok = _spec((s["n_slots"],), jnp.int32, sharding)
+    return (programs, _on(params, sharding), _on(cache, sharding), last_tok,
+            mp)
+
+
+def _pool(cache):
+    """Bytes of the paged pools and their page count P."""
+    leaves = jax.tree.leaves((cache["trunk"], cache["rem"]))
+    return (sum(x.size * x.dtype.itemsize for x in leaves),
+            leaves[0].shape[-3])
+
+
+def _pool_traffic(text, n_pool_pages):
+    """Copies, slices and slice updates of a pool-sized array in an
+    optimised HLO module: instructions (fused ones included) whose shape
+    has the pool's page count as a dimension."""
+    found = []
+    for m in re.finditer(r"^\s*(?:ROOT )?%?(\S+) = \w+\[([\d,]*)\]\S* "
+                         r"(copy|dynamic-slice|dynamic-update-slice)\(",
+                         text, re.M):
+        if str(n_pool_pages) in m.group(2).split(","):
+            found.append(f"{m.group(3)} {m.group(1)} [{m.group(2)}]")
+    return found
+
+
+@pytest.mark.parametrize("cell", list(CELLS))
+def test_engine_decode_step_updates_the_pool_in_place(one_chip, cell):
+    """At each benchmark cell's geometry the compiled decode step writes
+    its result into the donated pool (the output aliases every pool
+    byte) and never copies, slices or restacks a pool-sized array."""
+    (_, step, _, _), params, cache, last_tok, mp = _cell_programs(
+        cell, one_chip)
+    compiled = step.lower(params, cache, last_tok, mp=mp).compile()
+    text = _fits(compiled)
+    nbytes, n_pool_pages = _pool(cache)
+    assert compiled.memory_analysis().alias_size_in_bytes >= nbytes
+    assert _pool_traffic(text, n_pool_pages) == []
+
+
+def test_engine_prefill_updates_the_pool_in_place(one_chip):
+    """The chat cell's prefill of a 256-token prompt scatters into the
+    donated pool without a whole-pool copy."""
+    (prefill, _, _, _), params, cache, last_tok, _ = _cell_programs(
+        "qwen2.5-3b.chat", one_chip)
+    tokens = _spec((1, 256), jnp.int32, one_chip)
+    slot = _spec((), jnp.int32, one_chip)
+    compiled = prefill.lower(params, cache, last_tok, tokens,
+                             slot).compile()
+    text = _fits(compiled)
+    nbytes, n_pool_pages = _pool(cache)
+    assert compiled.memory_analysis().alias_size_in_bytes >= nbytes
+    assert _pool_traffic(text, n_pool_pages) == []

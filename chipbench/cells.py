@@ -1,13 +1,15 @@
-"""Find a cell's configuration, traffic, metrics and reference by name.
+"""Find a cell's configuration, traffic, metrics, architecture and
+reference by name.
 
 Nothing here knows a cell: ``BENCHMARK.json`` names them, and each part
 is a file of its own under ``chipbench/``.  A new cell, configuration,
-traffic mix or per-layer metric is new files and new entries, never an
-edit of one that exists.
+traffic mix, per-layer metric or model block is new files and new
+entries, never an edit of one that exists.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import os
@@ -36,10 +38,21 @@ def benchmark(root: str = ROOT) -> dict:
 
 
 def config(name: str, root: str = ROOT) -> dict:
-    spec = _load_json(os.path.join(root, "chipbench", "configs",
-                                   f"{name}.json"))
+    """The configuration file ``configs/<name>.json``; the architecture
+    module it names has to be there."""
+    where = f"configs/{name}.json"
+    spec = _load_json(os.path.join(root, "chipbench", where))
     if spec.get("name") != name:
-        raise ValueError(f"configs/{name}.json names {spec.get('name')!r}")
+        raise ValueError(f"{where} names {spec.get('name')!r}")
+    module = spec.get("architecture", {}).get("module")
+    if not module:
+        raise ValueError(f"{where} names no architecture.module: the file "
+                         f"under chipbench/architectures/ of its block")
+    try:
+        architecture(module)
+    except (FileNotFoundError, AttributeError) as e:
+        raise ValueError(f"{where}: architecture.module {module!r}: {e}"
+                         ) from e
     return spec
 
 
@@ -63,6 +76,34 @@ def metric_reader(name: str, root: str = ROOT) -> Callable:
     None where the run gave it nothing to read."""
     return _module(os.path.join(root, "chipbench", "metrics", f"{name}.py"),
                    f"reader for metric {name!r}").read
+
+
+# what an architecture module provides; ``spec`` is a configuration file,
+# ``contexts`` the tokens each row of a decode step attends (the new one
+# included), and the counts are what the algorithm needs
+ARCHITECTURE = (
+    "model_config",     # (spec) -> the program's ModelConfig
+    "shapes",           # (spec) -> {path: (shape, init, scale)}, see weights
+    "decode_flops",     # (spec, contexts) -> FLOPs of one decode step
+    "decode_bytes",     # (spec, contexts) -> bytes it reads and writes
+    "attn_flops",       # (spec, contexts) -> the attn_kv scope's FLOPs
+    "attn_bytes",       # (spec, contexts) -> and its bytes
+)
+
+
+@functools.lru_cache(maxsize=None)
+def architecture(name: str):
+    """``architectures/<name>.py``: everything that depends on the shape
+    of a configuration's block (``ARCHITECTURE``).  Always from this
+    checkout, the one whose weights and counts the harness runs, so a
+    configuration is checked against the module that ``modelspec.module``
+    then gives it."""
+    mod = _module(os.path.join(ROOT, "chipbench", "architectures",
+                               f"{name}.py"), f"architecture {name!r}")
+    missing = [f for f in ARCHITECTURE if not callable(getattr(mod, f, None))]
+    if missing:
+        raise AttributeError(f"architecture {name!r} provides no {missing}")
+    return mod
 
 
 def reference(name: str, root: str = ROOT):

@@ -8,7 +8,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
-from chipbench import cost, tails
+from chipbench import cost, modelspec, tails
 from chipbench.openloop import Window
 from chipbench.tracefile import Reduced
 
@@ -84,7 +84,8 @@ def decode_mfu(ctx):
     ms, steps = decode_step_ms(ctx), ctx.window.decode_steps
     if not ms or not steps:
         return None
-    flops = sum(cost.decode_flops(ctx.spec, c) for c in steps) / len(steps)
+    arch = modelspec.module(ctx.spec)
+    flops = sum(arch.decode_flops(ctx.spec, c) for c in steps) / len(steps)
     return 100.0 * flops / (ms / 1e3) / ctx.peak["bf16_flops"]
 
 

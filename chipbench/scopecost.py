@@ -1,10 +1,10 @@
-"""Operations and bytes that the ``attn_kv`` scope of a decode step
-needs, from shapes alone, as ``cost.py`` counts them for the whole step.
+"""The least time the ``attn_kv`` scope of a decode step needs, from
+shapes alone, as ``cost.py`` takes it for the whole step.
 
-Counted: the live KV of each decoded row read once and its new K/V
-written once, and the scores and values over that context.  The copy of
-the pool and the gather's materialized pages are not needed and not
-counted.
+The operations and bytes are the architecture module's (``attn_flops``,
+``attn_bytes``): the scores and values over each row's context, its live
+KV read once and its new K/V written once.  The copy of the pool and the
+gather's materialized pages are not needed and not counted.
 """
 from __future__ import annotations
 
@@ -13,22 +13,11 @@ from typing import Sequence
 from chipbench import modelspec
 
 
-def attn_flops(spec: dict, contexts: Sequence[int]) -> float:
-    """Scores and values of one decode step over rows whose contexts
-    (tokens attended, the new one included) are ``contexts``."""
-    z = modelspec.dims(spec)
-    return float(4 * z["layers"] * z["h"] * z["dh"] * sum(contexts))
-
-
-def attn_bytes(spec: dict, contexts: Sequence[int]) -> float:
-    kv = modelspec.kv_bytes_per_token(spec)
-    return float(kv * (sum(contexts) + len(contexts)))
-
-
 def attn_least_seconds(spec: dict, contexts: Sequence[int],
                        peak: dict) -> float:
-    return max(attn_flops(spec, contexts) / peak["bf16_flops"],
-               attn_bytes(spec, contexts) / peak["hbm_bytes_s"])
+    m = modelspec.module(spec)
+    return max(m.attn_flops(spec, contexts) / peak["bf16_flops"],
+               m.attn_bytes(spec, contexts) / peak["hbm_bytes_s"])
 
 
 def attn_roofline(spec: dict, steps: Sequence[Sequence[int]], peak: dict,

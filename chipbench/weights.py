@@ -4,18 +4,23 @@ One jitted program makes every leaf on the device, in the dtype served.
 Each leaf draws from the seed folded with its own path, so the reference
 can make the same weights again after the program's state is freed,
 without taking anything from the program.
+
+The leaves are the architecture module's ``shapes(spec)``: ``{path:
+(shape, init, scale)}``, the path the leaf's place in the program's
+pytree (``trunk/<i>/...`` for pattern position ``i``, its layer axis
+first; ``rem/<i>/...`` for remainder layer ``i``), ``init`` "normal"
+(N(0, scale)) or "one" (1 + N(0, scale)).
 """
 from __future__ import annotations
 
 import functools
-import math
 import zlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench.modelspec import dims
+from chipbench import modelspec
 
 DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
 
@@ -26,50 +31,30 @@ def seed_key(seed: int):
     return jax.random.PRNGKey(int(word))
 
 
-def shapes(spec: dict) -> dict:
-    """{path: (shape, init, scale)}: init is "normal" (N(0, scale)) or
-    "one" (1 + N(0, scale)).  Trunk leaves carry the layer axis first."""
-    z, a = dims(spec), spec["architecture"]
-    d, L, q, kv = z["d"], z["layers"], z["h"] * z["dh"], z["kvh"] * z["dh"]
-    std = float(spec["initializer_range"])
-    out = {"embed": ((z["vocab"], d), "normal", std)}
-
-    def norm(prefix, lead=()):
-        out[f"{prefix}/scale"] = (lead + (d,), "one", 0.02)
-        if a["norm"] == "ln":
-            out[f"{prefix}/bias"] = (lead + (d,), "normal", 0.02)
-
-    t = "trunk/0"
-    norm(f"{t}/norm1", (L,))
-    norm(f"{t}/norm2", (L,))
-    for name, (i, o) in {"wq": (d, q), "wk": (d, kv), "wv": (d, kv),
-                         "wo": (q, d)}.items():
-        out[f"{t}/attn/{name}"] = ((L, i, o), "normal", 1 / math.sqrt(i))
-    if a["qkv_bias"]:
-        for name, o in {"bq": q, "bk": kv, "bv": kv}.items():
-            out[f"{t}/attn/{name}"] = ((L, o), "normal", 0.02)
-    if a["o_bias"]:
-        out[f"{t}/attn/bo"] = ((L, d), "normal", 0.02)
-    for name, (i, o) in {"w_in": (d, z["ff"]), "w_gate": (d, z["ff"]),
-                         "w_out": (z["ff"], d)}.items():
-        out[f"{t}/ffn/{name}"] = ((L, i, o), "normal", 1 / math.sqrt(i))
-    norm("final_norm")
-    if not z["tied"]:
-        out["head"] = ((d, z["vocab"]), "normal", 1 / math.sqrt(d))
-    return out
-
-
 def _nest(flat: dict) -> dict:
-    tree: dict = {}
+    """The pytree of ``{"a/b/c": leaf}``: a node whose keys are all
+    numbers is a tuple, in their order, as the program's ``trunk`` (one
+    stacked group per pattern position) and ``rem`` (one per remainder
+    layer) are; both are there even when empty."""
+    tree: dict = {"trunk": {}, "rem": {}}
     for path, leaf in flat.items():
         node = tree
         *parents, last = path.split("/")
         for p in parents:
             node = node.setdefault(p, {})
         node[last] = leaf
-    tree["trunk"] = (tree["trunk"]["0"],)
-    tree["rem"] = ()
-    return tree
+    return _tuples(tree)
+
+
+def _tuples(node):
+    if not isinstance(node, dict):
+        return node
+    node = {k: _tuples(v) for k, v in node.items()}
+    if not all(k.isdigit() for k in node):
+        return node
+    if sorted(map(int, node)) != list(range(len(node))):
+        raise ValueError(f"positions {sorted(node)} are not 0..{len(node) - 1}")
+    return tuple(node[str(i)] for i in range(len(node)))
 
 
 @functools.lru_cache(maxsize=None)
@@ -89,7 +74,8 @@ def _maker(spec_items: tuple, dtype_name: str):
 
 def program(spec: dict):
     """The jitted program that makes the weights from a PRNG key."""
-    return _maker(tuple(sorted(shapes(spec).items())), spec["torch_dtype"])
+    table = modelspec.module(spec).shapes(spec)
+    return _maker(tuple(sorted(table.items())), spec["torch_dtype"])
 
 
 def make(spec: dict, seed: int):

@@ -247,9 +247,10 @@ def test_attention_cost_by_hand():
     spec = tiny_cell().config          # 2 layers, 4 heads of 32, 2 KV heads
     contexts = [10, 30]
     # scores and values: 4 FLOPs per head, head dim, layer and token
-    assert scopecost.attn_flops(spec, contexts) == 4 * 2 * 4 * 32 * 40
+    dense = cells.architecture("dense_decoder")
+    assert dense.attn_flops(spec, contexts) == 4 * 2 * 4 * 32 * 40
     # K and V, float32, 2 KV heads of 32 per layer: read 40, write 2
-    assert scopecost.attn_bytes(spec, contexts) == 2 * 2 * 2 * 32 * 4 * 42
+    assert dense.attn_bytes(spec, contexts) == 2 * 2 * 2 * 32 * 4 * 42
     peak = {"bf16_flops": 1e12, "hbm_bytes_s": 1e9}
     least = scopecost.attn_least_seconds(spec, contexts, peak)
     assert least == pytest.approx(2 * 2 * 2 * 32 * 4 * 42 / 1e9)
